@@ -28,7 +28,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
 
 NEG_INF = -1e30
 
@@ -252,7 +251,7 @@ def flash_fwd(
             pltpu.VMEM((qb, 1), jnp.float32),
             pltpu.VMEM((qb, d), jnp.float32),
         ],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
@@ -293,7 +292,7 @@ def flash_bwd(
         out_specs=pl.BlockSpec((1, qb, d), lambda bhi, qi, kj: (bhi, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((qb, d), jnp.float32)],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
@@ -326,7 +325,7 @@ def flash_bwd(
             pltpu.VMEM((kb, d), jnp.float32),
             pltpu.VMEM((kb, d), jnp.float32),
         ],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,

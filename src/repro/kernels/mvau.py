@@ -21,8 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
-
 from repro.kernels.packed_matmul import _decode_block
 
 
@@ -91,7 +89,7 @@ def mvau(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kb: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,

@@ -86,11 +86,10 @@ def stream_matmul(
     block multiples.
 
     x: (..., K); w: (K*bits/8, N) packed carrier or (K, N) dense (bits=0);
-    scale: (N,) or None. Returns (..., N) f32. On CPU the jnp reference is
-    used directly: interpret-mode DMA emulation is exercised by the kernel
-    equivalence tests, while hot paths (the budgeted serve step) keep the
-    reference math — bit-identical to the resident weight path, so a
-    VMEM-budgeted decode produces token-identical output.
+    scale: (N,) or None. Returns (..., N) f32. On the CPU backend the jnp
+    reference runs instead of the kernel (interpret-mode DMA emulation is
+    exercised by the kernel equivalence tests). Both multiply in f32, so
+    they match the resident weight path exactly only for f32 models.
     """
     from repro.kernels import weight_stream as _ws
     from repro.kernels.ref import stream_matmul_ref

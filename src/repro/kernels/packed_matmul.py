@@ -26,8 +26,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
-
 
 def _decode_block(w_packed, bits: int, bk: int, bn: int):
     """uint8 (bk*bits/8, bn) -> f32 (bk, bn) weight values, in-register.
@@ -37,10 +35,12 @@ def _decode_block(w_packed, bits: int, bk: int, bn: int):
     element — cheap relative to the 2*bk*bn MXU flops it feeds.
     """
     per = 8 // bits
-    mask = jnp.uint8(2**bits - 1)
+    # Mosaic has no uint8 -> f32 cast; widen to int32 first (uint8 -> int32
+    # and int32 -> f32 both lower), then shift and mask in 32-bit lanes.
+    wide = w_packed.astype(jnp.int32)
+    mask = 2**bits - 1
     planes = [
-        ((w_packed >> jnp.uint8(j * bits)) & mask).astype(jnp.float32)
-        for j in range(per)
+        ((wide >> (j * bits)) & mask).astype(jnp.float32) for j in range(per)
     ]
     # (bk/per, per, bn) -> (bk, bn): row-major interleave of the planes.
     codes = jnp.stack(planes, axis=1).reshape(bk, bn)
@@ -109,7 +109,7 @@ def packed_matmul(
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kb: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,

@@ -36,25 +36,18 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.packed_matmul import _decode_block
+
 
 def _decode_chunk(w_chunk, bits: int, ck: int, bn: int):
     """Carrier chunk -> f32 (ck, bn) weight values, in-register.
 
     bits=0: dense rows, cast only. bits in {1,2}: the ``pack_bits``
-    row-major interleave, matching ``packed_matmul._decode_block``.
+    row-major interleave, decoded by ``packed_matmul._decode_block``.
     """
     if bits == 0:
         return w_chunk.astype(jnp.float32)
-    per = 8 // bits
-    mask = jnp.uint8(2**bits - 1)
-    planes = [
-        ((w_chunk >> jnp.uint8(j * bits)) & mask).astype(jnp.float32)
-        for j in range(per)
-    ]
-    codes = jnp.stack(planes, axis=1).reshape(ck, bn)
-    if bits == 1:
-        return codes * 2.0 - 1.0  # {0,1} -> {-1,+1}
-    return codes - 1.0  # {0,1,2} -> {-1,0,+1}
+    return _decode_block(w_chunk, bits, ck, bn)
 
 
 def _stream_kernel(
@@ -144,7 +137,7 @@ def stream_matmul(
         grid=(n // bn,),
         in_specs=[
             pl.BlockSpec((m, k), lambda j: (0, 0)),  # x fully VMEM-resident
-            pl.BlockSpec(memory_space=pltpu.ANY),    # w stays in HBM
+            pl.BlockSpec(memory_space=pl.ANY),  # w stays in HBM
             pl.BlockSpec((1, bn), lambda j: (0, j)),
         ],
         out_specs=pl.BlockSpec((m, bn), lambda j: (0, j)),

@@ -31,6 +31,7 @@ import jax
 from repro.configs import get_config, get_smoke_config
 from repro.dist.mesh_axes import MeshView
 from repro.dist.placement import plan_engine_placement
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import lm
 from repro.models.config import PAGED_FAMILIES, PREFIX_CACHE_FAMILIES
 from repro.runtime.cluster import (
@@ -160,6 +161,7 @@ def build_cluster(cfg, full_cfg, params, args, spec):
 
 def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
+    use_compile_cache()
     try:
         cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
         full_cfg = get_config(args.arch)

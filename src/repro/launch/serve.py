@@ -26,6 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config, get_smoke_config
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import lm
 from repro.models.config import (
     PACKING_FAMILIES,
@@ -140,8 +141,11 @@ def build_pool_engine(cfg, params, args) -> Scheduler:
     )
 
 
-def run_pool_engine(cfg, params, args) -> dict:
-    sched = build_pool_engine(cfg, params, args)
+def run_pool_engine(cfg, params, args, sched: Scheduler | None = None) -> dict:
+    """Serve ``make_requests(args)`` to completion on ``sched`` (built from
+    ``args`` when not given) and summarize the run."""
+    if sched is None:
+        sched = build_pool_engine(cfg, params, args)
     for prompt in make_requests(args, cfg.vocab):
         sched.submit(prompt, args.gen_len)
     t0 = time.monotonic()
@@ -362,14 +366,16 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
+    use_compile_cache()
     try:
         cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     except ValueError as e:
         print(f"[serve] {e}")
         return 2
     if cfg.family == "encdec":
-        print("[serve] encdec serving is exercised in tests; use an LM arch")
-        return 0
+        print(f"[serve] {cfg.name} is encoder-decoder, which has no serving "
+              "path; use a decoder-only arch")
+        return 2
     if args.quant:
         if cfg.family not in PACKING_FAMILIES:
             print(f"[serve] note: --quant has no effect on family "

@@ -21,6 +21,7 @@ from repro.ckpt import CheckpointManager
 from repro.configs import get_config, get_smoke_config
 from repro.data.pipeline import TokenPipeline
 from repro.dist import sharding as shd
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_mesh, make_production_mesh
 from repro.models import lm
 from repro.optim.adamw import AdamW
@@ -39,7 +40,7 @@ def fit_mesh():
     return make_mesh((n // model, model), ("data", "model"))
 
 
-def main(argv=None) -> int:
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="smollm_360m")
     ap.add_argument("--smoke", action="store_true", help="reduced config")
@@ -54,38 +55,13 @@ def main(argv=None) -> int:
     ap.add_argument("--production-mesh", action="store_true")
     ap.add_argument("--quant", type=int, default=0, choices=[0, 1, 2])
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args(argv)
+    return ap
 
-    try:
-        cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
-    except ValueError as e:
-        print(f"[train] {e}")
-        return 2
-    if args.quant:
-        # Families with a dense FFN store 1/2-bit weights as packed uint8
-        # carriers (repro.models.lm), which are inference-only: no
-        # gradients, no optimizer moments (optim.adamw._is_frozen).
-        from repro.models.config import PACKING_FAMILIES
 
-        if cfg.family in PACKING_FAMILIES:
-            print(
-                f"[train] --quant {args.quant} is not trainable: "
-                f"{cfg.family!r} archs pack FFN weights into inference-only "
-                "uint8 carriers. Train dense (no --quant), then quantize the "
-                "checkpoint for serving (examples/pack_and_port.py, "
-                "launch/serve.py)."
-            )
-            return 2
-        # non-packing families: leave cfg untouched so the message stays
-        # true downstream (ckpt metadata, traffic modeling keyed on w_bits)
-        print(f"[train] note: --quant has no effect on family "
-              f"{cfg.family!r} (no dense FFN to pack); ignoring")
-    mesh = (
-        make_production_mesh() if args.production_mesh else fit_mesh()
-    )
-    print(f"[train] {cfg.name}: {cfg.n_params()/1e6:.1f}M params, "
-          f"mesh {dict(mesh.shape)}")
-
+def run_training(cfg, mesh, args):
+    """Initialise params sharded over ``mesh`` by the ``repro.dist``
+    policy and run ``args.steps`` steps (resuming from ``args.ckpt``).
+    Returns (params, opt_state, per-step log, start step)."""
     opt = AdamW(lr=args.lr)
     step_fn = make_train_step(
         cfg, opt, remat=args.remat, ce_chunk=args.ce_chunk
@@ -116,9 +92,45 @@ def main(argv=None) -> int:
             ),
         )
         params, opt_state, start = loop.restore_or_init(params, opt_state)
-        if start:
-            print(f"[train] resumed from step {start}")
         params, opt_state, log = loop.run(params, opt_state, start)
+    return params, opt_state, log, start
+
+
+def main(argv=None) -> int:
+    args = build_parser().parse_args(argv)
+    use_compile_cache()
+    try:
+        cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
+    except ValueError as e:
+        print(f"[train] {e}")
+        return 2
+    if args.quant:
+        # Families with a dense FFN store 1/2-bit weights as packed uint8
+        # carriers (repro.models.lm), which are inference-only: no
+        # gradients, no optimizer moments (optim.adamw._is_frozen).
+        from repro.models.config import PACKING_FAMILIES
+
+        if cfg.family in PACKING_FAMILIES:
+            print(
+                f"[train] --quant {args.quant} is not trainable: "
+                f"{cfg.family!r} archs pack FFN weights into inference-only "
+                "uint8 carriers. Train dense (no --quant), then quantize the "
+                "checkpoint for serving (examples/pack_and_port.py, "
+                "launch/serve.py)."
+            )
+            return 2
+        # non-packing families: leave cfg untouched so the message stays
+        # true downstream (ckpt metadata, traffic modeling keyed on w_bits)
+        print(f"[train] note: --quant has no effect on family "
+              f"{cfg.family!r} (no dense FFN to pack); ignoring")
+    mesh = (
+        make_production_mesh() if args.production_mesh else fit_mesh()
+    )
+    print(f"[train] {cfg.name}: {cfg.n_params()/1e6:.1f}M params, "
+          f"mesh {dict(mesh.shape)}")
+    params, opt_state, log, start = run_training(cfg, mesh, args)
+    if start:
+        print(f"[train] resumed from step {start}")
 
     first, last = log[0]["loss"], log[-1]["loss"]
     print(f"[train] steps {start}..{len(log)+start}: "

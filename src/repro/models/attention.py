@@ -21,7 +21,6 @@ import math
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 
 NEG_INF = -1e30
 
@@ -272,7 +271,7 @@ def flash_attention_seq_sharded(
 
     ba = tuple(a for a in batch_axes if a in mesh.axis_names)
     bspec = ba if ba else None
-    return compat.shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(
@@ -339,7 +338,7 @@ def decode_attention_split_d(
 
     ba = tuple(a for a in batch_axes if a in mesh.axis_names)
     spec = P(ba if ba else None, None, None, axis)
-    return compat.shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(spec, spec, spec, P()),

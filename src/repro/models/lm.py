@@ -110,8 +110,9 @@ def packed_swiglu(x, w1, w3, w2, bits: int):
 
 def _streamed_matmul(x: jnp.ndarray, w: Any, bits: int, depth: int):
     """Matmul with the weight left in HBM and streamed through a VMEM ring
-    (``kernels.weight_stream``; the jnp reference on CPU — same math as
-    the resident path, so budgeted decode stays token-identical)."""
+    (``kernels.weight_stream``; its jnp reference on the CPU backend).
+    Accumulates and scales in f32: token-identical to the resident path
+    for f32 models, within rounding of it for bf16 ones."""
     from repro.kernels.ops import stream_matmul
 
     kdim = x.shape[-1]

@@ -81,9 +81,7 @@ def pipeline_forward(
         )
         return outs
 
-    from repro import compat
-
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         stage_prog,
         mesh=mesh,
         in_specs=(P(axis), P()),

@@ -8,11 +8,12 @@ alongside the stacked layer leaves so the whole model still compiles as
 one ``lax.scan`` — HLO size stays flat in depth, and a ``lax.cond``
 selects the path per layer at run time.
 
-Numerics: on CPU the streamed branch resolves to the ``kernels.ref``
-oracle, whose math is identical to the resident branch — which is what
-makes ``--vmem-budget`` serve output token-identical to the unbudgeted
-path (the acceptance gate). On TPU the Pallas streaming kernel runs and
-matches to matmul-accumulation tolerance.
+Numerics: on the CPU backend the streamed branch resolves to the
+``kernels.ref`` oracle; on TPU the Pallas streaming kernel runs. Both
+multiply and scale in f32, the resident branch in the model dtype, so
+``--vmem-budget`` output is token-identical to the unbudgeted path for
+f32 models and within bf16 rounding of it for bf16 ones (``chip_smoke.py``
+states and checks the tolerance on a v5e).
 """
 
 from __future__ import annotations

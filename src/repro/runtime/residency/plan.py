@@ -171,7 +171,12 @@ class RuntimeResidencyPlan:
 
     @property
     def _chip(self) -> TpuChip:
-        return TPU_TIERS.get(self.chip.removeprefix("tpu_"), TPU_V5E)
+        tier = self.chip.removeprefix("tpu_")
+        if tier not in TPU_TIERS:
+            raise ValueError(
+                f"unknown chip {self.chip!r}; known: {sorted(TPU_TIERS)}"
+            )
+        return TPU_TIERS[tier]
 
     @property
     def resident_bytes(self) -> int:

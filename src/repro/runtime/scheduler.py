@@ -362,6 +362,10 @@ class Scheduler:
         # steps=)) — per-request spans then carry true phase boundaries
         # instead of round-granular ones.
         self.charge: Callable[..., None] | None = None
+        # logits hook: on_logits(rid, n, row) sees the (V,) row each
+        # request's n-th output token is sampled from (correctness checks
+        # compare these rows against a reference forward)
+        self.on_logits: Callable[[int, int, np.ndarray], None] | None = None
         # open decode slices: rid -> [t_slice_start, steps] for the
         # contiguous decode steps a lane ran this round (one span each)
         self._decode_open: dict[int, list] = {}
@@ -531,6 +535,8 @@ class Scheduler:
         a request's output never depends on lane placement or co-resident
         requests (the staggered-lane invariant extends to sampling).
         """
+        if self.on_logits is not None:
+            self.on_logits(req.rid, len(req.output), row)
         if self.sample is not None:  # legacy batched override
             return int(self.sample(row[None, :])[0])
         sp = self.sampling

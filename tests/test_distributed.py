@@ -29,11 +29,10 @@ def test_int8_allreduce_multidevice():
     out = run_forced("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from repro.compat import shard_map
         from repro.optim.compression import int8_allreduce
         mesh = jax.make_mesh((8,), ("data",))
         g = jnp.arange(64, dtype=jnp.float32).reshape(8, 8) / 64.0
-        f = shard_map(
+        f = jax.shard_map(
             lambda x: int8_allreduce(x[0], "data"),
             mesh=mesh, in_specs=P("data"), out_specs=P(),
         )
@@ -76,11 +75,12 @@ def test_small_mesh_train_step_shards():
         from jax.sharding import NamedSharding
         from repro.configs import get_smoke_config
         from repro.dist import sharding as shd
+        from repro.launch.mesh import make_mesh
         from repro.models import lm
         from repro.optim.adamw import AdamW
         from repro.runtime.steps import make_train_step
         cfg = get_smoke_config("llama3p2_1b")
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         params = lm.init_params(cfg, jax.random.key(0))
         opt = AdamW(warmup_steps=1)
         step = make_train_step(cfg, opt, remat="none", ce_chunk=16)
@@ -109,9 +109,10 @@ def test_moe_expert_parallel_consistency():
         from jax.sharding import NamedSharding
         from repro.configs import get_smoke_config
         from repro.dist import sharding as shd
+        from repro.launch.mesh import make_mesh
         from repro.models import lm
         cfg = get_smoke_config("olmoe_1b_7b")
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         params = lm.init_params(cfg, jax.random.key(1))
         toks = jnp.asarray(np.random.default_rng(0).integers(
             0, cfg.vocab, (2, 16)), jnp.int32)

@@ -92,6 +92,16 @@ def test_plan_budget_monotonicity_and_accounting():
         assert len(mask) == cfg.n_layers
 
 
+def test_plan_rejects_unknown_chip():
+    """A plan names its chip; one the tier table lacks is an error, not
+    a silent v5e."""
+    cfg = _cfg()
+    plan = compile_residency_plan(cfg, vmem_budget_bytes=2**20)
+    assert plan.resident_bytes >= 0
+    with pytest.raises(ValueError, match="unknown chip"):
+        dataclasses.replace(plan, chip="tpu_v9x").resident_bytes
+
+
 def test_plan_packed_blocks_shrink_with_bits():
     """1-bit carriers need ~1/32 the tiles of f32 — the FCMP packing win
     that makes the whole model resident where dense was not."""

@@ -189,3 +189,39 @@ def test_train_driver_rejects_quant_on_packed_arch(capsys):
     out = capsys.readouterr().out
     assert rc == 2
     assert "valid archs" in out
+
+
+def test_serve_rejects_encdec(capsys):
+    """An encoder-decoder arch has no serving path: exit 2, not a 0 that
+    served nothing."""
+    from repro.launch import serve as serve_launch
+
+    rc = serve_launch.main(["--arch", "whisper_tiny", "--smoke"])
+    assert rc == 2
+    assert "encoder-decoder" in capsys.readouterr().out
+
+
+@pytest.mark.parametrize("env_dir", [True, False], ids=["env", "default"])
+def test_compile_cache_placement(monkeypatch, tmp_path, env_dir):
+    """JAX_COMPILATION_CACHE_DIR is left to JAX; without it the cache goes
+    to the fixed .jax_cache at the repo root."""
+    from repro.launch import compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    if env_dir:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    try:
+        got = compile_cache.use_compile_cache()
+        if env_dir:
+            assert got == str(tmp_path)
+            assert jax.config.jax_compilation_cache_dir == before
+        else:
+            assert got == str(compile_cache.DEFAULT_DIR)
+            assert compile_cache.DEFAULT_DIR.parent.joinpath(
+                "chip_smoke.py"
+            ).is_file()
+            assert jax.config.jax_compilation_cache_dir == got
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
