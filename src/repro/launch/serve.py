@@ -97,8 +97,14 @@ def build_pool_engine(cfg, params, args) -> Scheduler:
     from repro.runtime.memledger import MemLedger, MemPressureMonitor
 
     # no engine stamp: standalone round records carry none either, and
-    # the ledger/metrics engine keys must agree for validate_ledger
-    ledger = MemLedger(time.monotonic, tracker=tracker)
+    # the ledger/metrics engine keys must agree for validate_ledger. With
+    # no tracker nothing would read its records: build none, so the pool
+    # snapshots nothing on each mutation
+    ledger = (
+        MemLedger(time.monotonic, tracker=tracker)
+        if tracker is not None
+        else None
+    )
     mem_monitor = MemPressureMonitor()
     speculator = None
     if getattr(args, "speculate", ""):
@@ -194,7 +200,7 @@ def run_pool_engine(cfg, params, args, sched: Scheduler | None = None) -> dict:
         ),
         "span_records": sched.spans.n_spans if sched.spans else 0,
         "mem": sched.mem_monitor.summary(now=time.monotonic()),
-        "mem_records": sched.ledger.n_records,
+        "mem_records": sched.ledger.n_records if sched.ledger else 0,
         "fragmentation": sched.pool.fragmentation_report(),
         "outputs": outputs,
     }

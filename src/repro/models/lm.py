@@ -818,6 +818,35 @@ def prefill_with_cache(
     return lg, ks, vs
 
 
+# the named scopes of the paged serving steps: they reach the compiled
+# step's op metadata (``op_name``), so a device profile's operations can
+# be put down to the attention sub-block, its KV pool write and gather,
+# the FFN and the unembedding
+STEP_SCOPES = ("attention", "kv_write", "kv_gather", "ffn", "logits")
+
+
+def _paged_kv(pk, pv, write_rows, k, v, row_table):
+    """Scatter new K/V rows into one layer's pool, then gather every
+    lane's rows through ``row_table``: (new pk, new pv, gathered k,
+    gathered v)."""
+    with jax.named_scope("kv_write"):
+        pk = pk.at[write_rows].set(k)
+        pv = pv.at[write_rows].set(v)
+    with jax.named_scope("kv_gather"):
+        return pk, pv, pk[row_table], pv[row_table]
+
+
+def _scoped_logits(params, cfg: ModelConfig, x, last_idx=None):
+    """Final norm and unembedding (of position ``last_idx`` alone when
+    given), under the ``logits`` scope."""
+    with jax.named_scope("logits"):
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        if last_idx is not None:
+            x = jax.lax.dynamic_slice_in_dim(x, last_idx, 1, axis=1)
+        table = params["embed"] if cfg.tie_embeddings else params["unembed"]
+        return unembed_logits(x, table, cfg.vocab)
+
+
 def decode_step_paged(
     params: dict,
     cfg: ModelConfig,
@@ -871,29 +900,32 @@ def decode_step_paged(
             streamed = None
         else:
             lp, pk, pv, streamed = lp_kv
-        q, k, v = _decode_qkv(lp, cfg, x, pos_b)
-        pk = pk.at[write_rows].set(k[:, 0])
-        pv = pv.at[write_rows].set(v[:, 0])
-        o = attn.decode_attention(
-            q, pk[row_table], pv[row_table], (lengths + 1)[:, None],
-            window=cfg.sliding_window,
-        )
-        x = x + dense(o.reshape(b, 1, -1), lp["wo"])
+        with jax.named_scope("attention"):
+            q, k, v = _decode_qkv(lp, cfg, x, pos_b)
+            pk, pv, kg, vg = _paged_kv(
+                pk, pv, write_rows, k[:, 0], v[:, 0], row_table
+            )
+            o = attn.decode_attention(
+                q, kg, vg, (lengths + 1)[:, None], window=cfg.sliding_window
+            )
+            x = x + dense(o.reshape(b, 1, -1), lp["wo"])
+        with jax.named_scope("ffn"):
+            if moe:
+                x, counts = _ffn_block(
+                    lp, cfg, x, dropless=True, expert_mask=streamed,
+                    stream_depth=stream_depth,
+                )
+            elif stream_mask is None:
+                x, a = _ffn_block(lp, cfg, x)
+            else:
+                x, a = jax.lax.cond(
+                    streamed,
+                    lambda h: _ffn_block_streamed(lp, cfg, h, stream_depth),
+                    lambda h: _ffn_block(lp, cfg, h),
+                    x,
+                )
         if moe:
-            x, counts = _ffn_block(
-                lp, cfg, x, dropless=True, expert_mask=streamed,
-                stream_depth=stream_depth,
-            )
             return (x, aux), (pk, pv, counts)
-        if stream_mask is None:
-            x, a = _ffn_block(lp, cfg, x)
-        else:
-            x, a = jax.lax.cond(
-                streamed,
-                lambda h: _ffn_block_streamed(lp, cfg, h, stream_depth),
-                lambda h: _ffn_block(lp, cfg, h),
-                x,
-            )
         return (x, aux + a), (pk, pv)
 
     xs = (params["layers"], pool_k, pool_v)
@@ -902,9 +934,7 @@ def decode_step_paged(
     (x, _), outs = jax.lax.scan(
         layer_fn, (x, jnp.zeros((), jnp.float32)), xs
     )
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    table = params["embed"] if cfg.tie_embeddings else params["unembed"]
-    lg = unembed_logits(x, table, cfg.vocab)
+    lg = _scoped_logits(params, cfg, x)
     if moe:
         pks, pvs, counts = outs
         return lg, pks, pvs, counts
@@ -958,20 +988,22 @@ def prefill_chunk_paged(
     def layer_fn(carry, lp_kv):
         x, aux = carry
         lp, pk, pv = lp_kv
-        q, k, v = _qkv(lp, cfg, x, positions)
-        pk = pk.at[write_rows].set(k)
-        pv = pv.at[write_rows].set(v)
-        # gathered rows sit at logical positions 0..S_max-1; rows past the
-        # chunk (scratch padding included) are masked by causality
-        o = attn.chunk_attention(
-            q, pk[row_table], pv[row_table], positions,
-            window=cfg.sliding_window,
-        )
-        x = x + dense(o.reshape(b, c, -1), lp["wo"])
+        with jax.named_scope("attention"):
+            q, k, v = _qkv(lp, cfg, x, positions)
+            pk, pv, kg, vg = _paged_kv(pk, pv, write_rows, k, v, row_table)
+            # gathered rows sit at logical positions 0..S_max-1; rows past
+            # the chunk (scratch padding included) are masked by causality
+            o = attn.chunk_attention(
+                q, kg, vg, positions, window=cfg.sliding_window
+            )
+            x = x + dense(o.reshape(b, c, -1), lp["wo"])
+        with jax.named_scope("ffn"):
+            if moe:
+                x, counts = _ffn_block(lp, cfg, x, dropless=True)
+            else:
+                x, a = _ffn_block(lp, cfg, x)
         if moe:
-            x, counts = _ffn_block(lp, cfg, x, dropless=True)
             return (x, aux), (pk, pv, counts)
-        x, a = _ffn_block(lp, cfg, x)
         return (x, aux + a), (pk, pv)
 
     (x, _), outs = jax.lax.scan(
@@ -979,10 +1011,7 @@ def prefill_chunk_paged(
         (x, jnp.zeros((), jnp.float32)),
         (params["layers"], pool_k, pool_v),
     )
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    x_last = jax.lax.dynamic_slice_in_dim(x, last_idx, 1, axis=1)
-    table = params["embed"] if cfg.tie_embeddings else params["unembed"]
-    lg = unembed_logits(x_last, table, cfg.vocab)
+    lg = _scoped_logits(params, cfg, x, last_idx)
     if moe:
         pks, pvs, counts = outs
         return lg, pks, pvs, counts
@@ -1032,18 +1061,20 @@ def verify_chunk_paged(
     def layer_fn(carry, lp_kv):
         x, aux = carry
         lp, pk, pv = lp_kv
-        q, k, v = _qkv(lp, cfg, x, positions)
-        pk = pk.at[write_rows].set(k)
-        pv = pv.at[write_rows].set(v)
-        o = attn.chunk_attention(
-            q, pk[row_table], pv[row_table], positions,
-            window=cfg.sliding_window,
-        )
-        x = x + dense(o.reshape(b, c, -1), lp["wo"])
+        with jax.named_scope("attention"):
+            q, k, v = _qkv(lp, cfg, x, positions)
+            pk, pv, kg, vg = _paged_kv(pk, pv, write_rows, k, v, row_table)
+            o = attn.chunk_attention(
+                q, kg, vg, positions, window=cfg.sliding_window
+            )
+            x = x + dense(o.reshape(b, c, -1), lp["wo"])
+        with jax.named_scope("ffn"):
+            if moe:
+                x, counts = _ffn_block(lp, cfg, x, dropless=True)
+            else:
+                x, a = _ffn_block(lp, cfg, x)
         if moe:
-            x, counts = _ffn_block(lp, cfg, x, dropless=True)
             return (x, aux), (pk, pv, counts)
-        x, a = _ffn_block(lp, cfg, x)
         return (x, aux + a), (pk, pv)
 
     (x, _), outs = jax.lax.scan(
@@ -1051,9 +1082,7 @@ def verify_chunk_paged(
         (x, jnp.zeros((), jnp.float32)),
         (params["layers"], pool_k, pool_v),
     )
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    table = params["embed"] if cfg.tie_embeddings else params["unembed"]
-    lg = unembed_logits(x, table, cfg.vocab)
+    lg = _scoped_logits(params, cfg, x)
     if moe:
         pks, pvs, counts = outs
         return lg, pks, pvs, counts
@@ -1196,14 +1225,15 @@ def decode_step_paged_hybrid(
             return x, (st, *bufs)
 
         x, new_states = jax.lax.scan(inner, x, (lps, sts, cxs, cbs, ccs))
-        q, k, v = _decode_qkv(shared, cfg, x, pos_b)
-        pk = pk.at[write_rows].set(k[:, 0])
-        pv = pv.at[write_rows].set(v[:, 0])
-        o = attn.decode_attention(
-            q, pk[row_table], pv[row_table], (lengths + 1)[:, None]
-        )
-        x = x + dense(o.reshape(b, 1, -1), shared["wo"])
-        x, _ = _ffn_block(shared, cfg, x)
+        with jax.named_scope("attention"):
+            q, k, v = _decode_qkv(shared, cfg, x, pos_b)
+            pk, pv, kg, vg = _paged_kv(
+                pk, pv, write_rows, k[:, 0], v[:, 0], row_table
+            )
+            o = attn.decode_attention(q, kg, vg, (lengths + 1)[:, None])
+            x = x + dense(o.reshape(b, 1, -1), shared["wo"])
+        with jax.named_scope("ffn"):
+            x, _ = _ffn_block(shared, cfg, x)
         return x, (new_states, pk, pv)
 
     x, (new_states, pks, pvs) = jax.lax.scan(
@@ -1215,9 +1245,7 @@ def decode_step_paged_hybrid(
         "ssm": merge(sts), "conv_x": merge(cxs),
         "conv_b": merge(cbs), "conv_c": merge(ccs),
     }
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    table = params["embed"] if cfg.tie_embeddings else params["unembed"]
-    return unembed_logits(x, table, cfg.vocab), pks, pvs, new_lane
+    return _scoped_logits(params, cfg, x), pks, pvs, new_lane
 
 
 def prefill_suffix_paged_hybrid(
@@ -1283,12 +1311,13 @@ def prefill_suffix_paged_hybrid(
             return x, (st, *bufs)
 
         x, new_states = jax.lax.scan(inner, x, (lps, sts, cxs, cbs, ccs))
-        q, k, v = _qkv(shared, cfg, x, positions)
-        pk = pk.at[write_rows].set(k)
-        pv = pv.at[write_rows].set(v)
-        o = attn.chunk_attention(q, pk[row_table], pv[row_table], positions)
-        x = x + dense(o.reshape(b, c, -1), shared["wo"])
-        x, _ = _ffn_block(shared, cfg, x)
+        with jax.named_scope("attention"):
+            q, k, v = _qkv(shared, cfg, x, positions)
+            pk, pv, kg, vg = _paged_kv(pk, pv, write_rows, k, v, row_table)
+            o = attn.chunk_attention(q, kg, vg, positions)
+            x = x + dense(o.reshape(b, c, -1), shared["wo"])
+        with jax.named_scope("ffn"):
+            x, _ = _ffn_block(shared, cfg, x)
         return x, (new_states, pk, pv)
 
     x, (new_states, pks, pvs) = jax.lax.scan(
@@ -1300,10 +1329,7 @@ def prefill_suffix_paged_hybrid(
         "ssm": merge(sts), "conv_x": merge(cxs),
         "conv_b": merge(cbs), "conv_c": merge(ccs),
     }
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    x_last = jax.lax.dynamic_slice_in_dim(x, last_idx, 1, axis=1)
-    table = params["embed"] if cfg.tie_embeddings else params["unembed"]
-    return unembed_logits(x_last, table, cfg.vocab), pks, pvs, new_lane
+    return _scoped_logits(params, cfg, x, last_idx), pks, pvs, new_lane
 
 
 # --------------------------------------------------------------------------

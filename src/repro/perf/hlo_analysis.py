@@ -502,3 +502,28 @@ def top_contributors(text: str, metric: str = "traffic", n: int = 20):
             rows.append((val, cname[:36], ins.name, ins.opcode, ins.shape[:44], meta[:70]))
     rows.sort(reverse=True)
     return rows[:n]
+
+
+_OP_NAME = re.compile(r'metadata=\{[^}]*?op_name="([^"]*)"')
+
+
+def scope_table(text: str, scopes) -> dict[str, str]:
+    """{instruction name: scope path} of the instructions whose
+    ``op_name`` metadata passes through any of ``scopes`` (names given
+    with ``jax.named_scope``); the path keeps those names alone,
+    outermost first (``"attention/kv_gather"``). A fusion carries the
+    metadata of its root, so the table names what a device profile's
+    operations (one per instruction run) were computing. Line by line,
+    so it reads the compiled module's text and a lowered module printed
+    with ``debug_info`` alike."""
+    wanted = set(scopes)
+    out: dict[str, str] = {}
+    for line in text.splitlines():
+        head = _INSTR_HEAD.match(line)
+        meta = head and _OP_NAME.search(line, head.end())
+        if not meta:
+            continue
+        path = [p for p in meta.group(1).split("/") if p in wanted]
+        if path:
+            out[head.group(1)] = "/".join(path)
+    return out

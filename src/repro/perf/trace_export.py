@@ -59,7 +59,8 @@ def to_trace_events(records: Iterable[dict]) -> dict:
         if r.get("kind") == "hparams" and r.get("surface") == "engine":
             engines[int(r["engine"])] = str(r.get("role", "both"))
 
-    spans = [r for r in records if r.get("kind") == "span"]
+    # request spans only: round phases and scope tables carry no rid
+    spans = [r for r in records if r.get("kind") == "span" and "rid" in r]
     by_rid: dict[int, list[dict]] = {}
     for s in spans:
         by_rid.setdefault(int(s["rid"]), []).append(s)

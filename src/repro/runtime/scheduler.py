@@ -41,11 +41,14 @@ from repro.models.config import (
     ModelConfig,
 )
 from repro.models.lm import (
+    STEP_SCOPES,
     SamplingParams,
     init_ssm_lane_state,
     sample_logits,
 )
+from repro.perf.hlo_analysis import scope_table
 from repro.runtime.kv_pool import KVPool
+from repro.runtime.spans import phase_annotation
 from repro.runtime.speculative import SPEC_FAMILIES, LaneDraft
 from repro.runtime.steps import (
     make_chunk_prefill_step,
@@ -353,8 +356,9 @@ class Scheduler:
         self._emit_ttft_base = 0
         # request-lifecycle spans (runtime.spans.SpanRecorder): queue /
         # prefix_lookup / prefill chunk / decode slice per request, with
-        # exact-decomposition tiling. A fleet Engine passes a recorder on
-        # its virtual clock; bare schedulers may pass a wall-clock one.
+        # exact-decomposition tiling, and the round's own phases. A fleet
+        # Engine passes a recorder on its virtual clock; bare schedulers
+        # may pass a wall-clock one.
         self.spans = spans
         # virtual-time charge hook: a fleet Engine installs this so each
         # unit of work advances the virtual clock at the instant it
@@ -411,6 +415,38 @@ class Scheduler:
             if residency is not None:
                 hp["residency"] = residency.summary()
             tracker.log_hyperparameters(hp)
+
+    # ---------------- tracing ----------------
+
+    @property
+    def spans(self):
+        return self._spans
+
+    @spans.setter
+    def spans(self, recorder) -> None:
+        """Attach a span recorder. One with a tracker also records the
+        decode program's scope table, here: a traced serving run attaches
+        its recorder after warm-up, so the lowering this takes is served
+        from JAX's caches and nothing compiles while requests run (a cold
+        scheduler compiles its decode step here, not at its first step)."""
+        self._spans = recorder
+        if (
+            recorder is not None
+            and recorder.tracker is not None
+            and self.handoff is None
+            and self.speculative is None
+        ):
+            compiled = self._decode.lower(*self._decode_args()).compile()
+            recorder.step_scopes(
+                "decode", scope_table(compiled.as_text(), STEP_SCOPES)
+            )
+
+    def _phase(self, name: str):
+        """Round phase ``name`` (listed in ``runtime.spans``): the
+        profiler annotation, and a record when the recorder is tracked."""
+        if self._spans is None:
+            return phase_annotation(name)
+        return self._spans.phase(name, round=self.stats.rounds)
 
     # ---------------- submission ----------------
 
@@ -859,71 +895,74 @@ class Scheduler:
         the recurrence exactly where the previous one stopped, which is
         why chunked hybrid prefill is token-identical to single-shot.
         """
-        rid = self.active[slot]
-        req = self.requests[rid]
-        c0 = self._chunk_cursor[rid]
-        p = len(req.prompt)
-        c = self.prefill_chunk
-        n = min(c, p - c0)
-        t0 = self.spans.now() if self.spans is not None else 0.0
-        self.pool.note_tokens(rid, c0 + n)
-        rows = self.pool.rows_of(rid)[c0 : c0 + n]
-        row_table = self.pool.rows_of(rid, pad_to=self.s_max)[None]
-        if self.cfg.family == "hybrid":
-            logits, self.pool.k, self.pool.v, new_lane = self._hybrid_suffix(
-                self.params,
-                jnp.asarray(req.prompt[c0 : c0 + n][None]),
-                self.pool.k,
-                self.pool.v,
-                jnp.asarray(row_table),
-                jnp.asarray(rows[None]),
-                jnp.asarray(c0, jnp.int32),
-                jnp.asarray(n - 1, jnp.int32),
-                self._chunk_lane[rid],
-            )
-            self._chunk_lane[rid] = new_lane
-        else:
-            scratch = int(self.pool.scratch_rows(1)[0])
-            write_rows = np.full((1, c), scratch, np.int32)
-            write_rows[0, :n] = rows
-            tokens = np.zeros((1, c), np.int32)
-            tokens[0, :n] = req.prompt[c0 : c0 + n]
-            out = self._chunk_prefill(
-                self.params,
-                jnp.asarray(tokens),
-                self.pool.k,
-                self.pool.v,
-                jnp.asarray(row_table),
-                jnp.asarray(write_rows),
-                jnp.asarray(c0, jnp.int32),
-                jnp.asarray(n - 1, jnp.int32),
-            )
-            if self.cfg.family == "moe":
-                logits, self.pool.k, self.pool.v, counts = out
-                self._note_expert_counts(counts)
-            else:
-                logits, self.pool.k, self.pool.v = out
-        self.stats.prefill_steps += 1
-        self.stats.prefill_tokens += n
-        if self.charge is not None:
-            self.charge("prefill", tokens=n, steps=1)
-        if self.spans is not None:
-            self.spans.mark(
-                rid, "prefill", t0, self.spans.now(), tokens=n, chunk_start=c0
-            )
-        self._chunk_cursor[rid] = c0 + n
-        if c0 + n >= p:
-            del self._chunk_cursor[rid]
+        with self._phase("prefill_chunk"):
+            rid = self.active[slot]
+            req = self.requests[rid]
+            c0 = self._chunk_cursor[rid]
+            p = len(req.prompt)
+            c = self.prefill_chunk
+            n = min(c, p - c0)
+            t0 = self.spans.now() if self.spans is not None else 0.0
+            self.pool.note_tokens(rid, c0 + n)
+            rows = self.pool.rows_of(rid)[c0 : c0 + n]
+            row_table = self.pool.rows_of(rid, pad_to=self.s_max)[None]
             if self.cfg.family == "hybrid":
-                # the post-prompt state moves into the decode lane slot
-                lane = self._chunk_lane.pop(rid)
-                self._lane_state = jax.tree.map(
-                    lambda dst, src: dst.at[:, slot].set(src[:, 0]),
-                    self._lane_state,
-                    lane,
+                logits, self.pool.k, self.pool.v, self._chunk_lane[rid] = (
+                    self._hybrid_suffix(
+                        self.params,
+                        jnp.asarray(req.prompt[c0 : c0 + n][None]),
+                        self.pool.k,
+                        self.pool.v,
+                        jnp.asarray(row_table),
+                        jnp.asarray(rows[None]),
+                        jnp.asarray(c0, jnp.int32),
+                        jnp.asarray(n - 1, jnp.int32),
+                        self._chunk_lane[rid],
+                    )
                 )
-            first = self._sample_one(req, np.asarray(logits[0, 0, :]))
-            self._start_decode(slot, req, first)
+            else:
+                scratch = int(self.pool.scratch_rows(1)[0])
+                write_rows = np.full((1, c), scratch, np.int32)
+                write_rows[0, :n] = rows
+                tokens = np.zeros((1, c), np.int32)
+                tokens[0, :n] = req.prompt[c0 : c0 + n]
+                out = self._chunk_prefill(
+                    self.params,
+                    jnp.asarray(tokens),
+                    self.pool.k,
+                    self.pool.v,
+                    jnp.asarray(row_table),
+                    jnp.asarray(write_rows),
+                    jnp.asarray(c0, jnp.int32),
+                    jnp.asarray(n - 1, jnp.int32),
+                )
+                if self.cfg.family == "moe":
+                    logits, self.pool.k, self.pool.v, counts = out
+                    self._note_expert_counts(counts)
+                else:
+                    logits, self.pool.k, self.pool.v = out
+            self.stats.prefill_steps += 1
+            self.stats.prefill_tokens += n
+            if self.charge is not None:
+                self.charge("prefill", tokens=n, steps=1)
+            if self.spans is not None:
+                self.spans.mark(
+                    rid, "prefill", t0, self.spans.now(), tokens=n,
+                    chunk_start=c0,
+                )
+            self._chunk_cursor[rid] = c0 + n
+            if c0 + n >= p:
+                del self._chunk_cursor[rid]
+                if self.cfg.family == "hybrid":
+                    # the post-prompt state moves into the decode lane slot
+                    lane = self._chunk_lane.pop(rid)
+                    self._lane_state = jax.tree.map(
+                        lambda dst, src: dst.at[:, slot].set(src[:, 0]),
+                        self._lane_state,
+                        lane,
+                    )
+                first = self._sample_one(req, np.asarray(logits[0, 0, :]))
+                self._start_decode(slot, req, first)
 
     def _commit_generated(self, slot: int, req: Request) -> None:
         """Re-index the finished conversation — prompt *plus* generated
@@ -977,49 +1016,45 @@ class Scheduler:
             and self.requests[rid].state is RequestState.DECODE
         )
 
-    def _decode_step(self) -> None:
-        t0_step = self.spans.now() if self.spans is not None else 0.0
-        for i, rid in enumerate(self.active):
-            if not self._decoding(rid):
-                continue  # empty lane, or a mid-chunked-prefill reservation
-            # room for the incoming token's KV row
-            before = self.pool.blocks_held(rid)
-            self.pool.note_tokens(rid, int(self._lengths[i]) + 1)
-            if self.pool.blocks_held(rid) != before:
-                self._row_table[i] = self.pool.rows_of(rid, pad_to=self.s_max)
-                self._table_dirty = True
-        if self._table_dirty:
-            self._row_table_dev = jnp.asarray(self._row_table)
-            self._table_dirty = False
+    def _decode_args(self) -> tuple:
+        """The decode program's arguments for the current lanes."""
+        args = (
+            self.params,
+            jnp.asarray(self._token),
+            self.pool.k,
+            self.pool.v,
+            self._row_table_dev,
+            jnp.asarray(self._lengths),
+        )
         if self.cfg.family == "hybrid":
-            logits, self.pool.k, self.pool.v, self._lane_state = self._decode(
-                self.params,
-                jnp.asarray(self._token),
-                self.pool.k,
-                self.pool.v,
-                self._row_table_dev,
-                jnp.asarray(self._lengths),
-                self._lane_state,
-            )
+            args += (self._lane_state,)
+        return args
+
+    def _decode_step(self) -> None:
+        with self._phase("decode_dispatch"):
+            t0_step = self.spans.now() if self.spans is not None else 0.0
+            for i, rid in enumerate(self.active):
+                if not self._decoding(rid):
+                    continue  # empty lane, or a mid-chunked-prefill lane
+                # room for the incoming token's KV row
+                before = self.pool.blocks_held(rid)
+                self.pool.note_tokens(rid, int(self._lengths[i]) + 1)
+                if self.pool.blocks_held(rid) != before:
+                    self._row_table[i] = self.pool.rows_of(
+                        rid, pad_to=self.s_max
+                    )
+                    self._table_dirty = True
+            if self._table_dirty:
+                self._row_table_dev = jnp.asarray(self._row_table)
+                self._table_dirty = False
+            out = self._decode(*self._decode_args())
+        if self.cfg.family == "hybrid":
+            logits, self.pool.k, self.pool.v, self._lane_state = out
         elif self.cfg.family == "moe":
-            logits, self.pool.k, self.pool.v, counts = self._decode(
-                self.params,
-                jnp.asarray(self._token),
-                self.pool.k,
-                self.pool.v,
-                self._row_table_dev,
-                jnp.asarray(self._lengths),
-            )
+            logits, self.pool.k, self.pool.v, counts = out
             self._note_expert_counts(counts)
         else:
-            logits, self.pool.k, self.pool.v = self._decode(
-                self.params,
-                jnp.asarray(self._token),
-                self.pool.k,
-                self.pool.v,
-                self._row_table_dev,
-                jnp.asarray(self._lengths),
-            )
+            logits, self.pool.k, self.pool.v = out
         self.stats.decode_steps += 1
         if self.charge is not None:
             self.charge("decode", steps=1)
@@ -1033,25 +1068,27 @@ class Scheduler:
                         self._decode_open[rid] = [t0_step, 1]
                     else:
                         sl[1] += 1
-        rows = np.asarray(logits[:, 0, :])
-        pool_st = self.pool.stats()
-        util = pool_st.utilization
-        self.stats.shared_blocks_peak = max(
-            self.stats.shared_blocks_peak, pool_st.shared_blocks
-        )
-        self.stats.util_samples_any.append(util)
-        if all(r is not None for r in self.active):
-            self.stats.util_samples.append(util)
-        for i, rid in enumerate(self.active):
-            if not self._decoding(rid):
-                continue
-            req = self.requests[rid]
-            nxt = self._sample_one(req, rows[i])
-            req.output.append(nxt)
-            self._token[i, 0] = nxt
-            self._lengths[i] += 1
-            if len(req.output) >= req.max_new_tokens:
-                self._complete(i)
+        with self._phase("logits_fetch"):
+            rows = np.asarray(logits[:, 0, :])
+        with self._phase("sample"):
+            pool_st = self.pool.stats()
+            util = pool_st.utilization
+            self.stats.shared_blocks_peak = max(
+                self.stats.shared_blocks_peak, pool_st.shared_blocks
+            )
+            self.stats.util_samples_any.append(util)
+            if all(r is not None for r in self.active):
+                self.stats.util_samples.append(util)
+            for i, rid in enumerate(self.active):
+                if not self._decoding(rid):
+                    continue
+                req = self.requests[rid]
+                nxt = self._sample_one(req, rows[i])
+                req.output.append(nxt)
+                self._token[i, 0] = nxt
+                self._lengths[i] += 1
+                if len(req.output) >= req.max_new_tokens:
+                    self._complete(i)
 
     def _spec_step(self) -> None:
         """One speculate-and-verify cycle over every decoding lane.
@@ -1113,40 +1150,43 @@ class Scheduler:
             if self.charge is not None and draft_steps:
                 self.charge("draft", steps=draft_steps)
         t1 = self.spans.now() if self.spans is not None else t0
-        # room for every lane's chain rows: draft-class blocks, settled
-        # (or fully returned) by end_draft after acceptance
-        for i, rid in lanes:
-            before = self.pool.blocks_held(rid)
-            self.pool.begin_draft(rid, int(self._lengths[i]) + k_eff[rid])
-            if self.pool.blocks_held(rid) != before:
-                self._row_table[i] = self.pool.rows_of(
-                    rid, pad_to=self.s_max
+        with self._phase("decode_dispatch"):
+            # room for every lane's chain rows: draft-class blocks,
+            # settled (or fully returned) by end_draft after acceptance
+            for i, rid in lanes:
+                before = self.pool.blocks_held(rid)
+                self.pool.begin_draft(
+                    rid, int(self._lengths[i]) + k_eff[rid]
                 )
-                self._table_dirty = True
-        if self._table_dirty:
-            self._row_table_dev = jnp.asarray(self._row_table)
-            self._table_dirty = False
-        scratch = int(self.pool.scratch_rows(1)[0])
-        tokens = np.zeros((self.slots, kmax), np.int32)
-        write_rows = np.full((self.slots, kmax), scratch, np.int32)
-        starts = np.zeros((self.slots,), np.int32)
-        for i, rid in lanes:
-            ke = k_eff[rid]
-            n = int(self._lengths[i])
-            tokens[i, 0] = self._token[i, 0]
-            if ke > 1:
-                tokens[i, 1:ke] = props[rid][: ke - 1]
-            write_rows[i, :ke] = self.pool.rows_of(rid)[n : n + ke]
-            starts[i] = n
-        out = self._verify(
-            self.params,
-            jnp.asarray(tokens),
-            self.pool.k,
-            self.pool.v,
-            self._row_table_dev,
-            jnp.asarray(write_rows),
-            jnp.asarray(starts),
-        )
+                if self.pool.blocks_held(rid) != before:
+                    self._row_table[i] = self.pool.rows_of(
+                        rid, pad_to=self.s_max
+                    )
+                    self._table_dirty = True
+            if self._table_dirty:
+                self._row_table_dev = jnp.asarray(self._row_table)
+                self._table_dirty = False
+            scratch = int(self.pool.scratch_rows(1)[0])
+            tokens = np.zeros((self.slots, kmax), np.int32)
+            write_rows = np.full((self.slots, kmax), scratch, np.int32)
+            starts = np.zeros((self.slots,), np.int32)
+            for i, rid in lanes:
+                ke = k_eff[rid]
+                n = int(self._lengths[i])
+                tokens[i, 0] = self._token[i, 0]
+                if ke > 1:
+                    tokens[i, 1:ke] = props[rid][: ke - 1]
+                write_rows[i, :ke] = self.pool.rows_of(rid)[n : n + ke]
+                starts[i] = n
+            out = self._verify(
+                self.params,
+                jnp.asarray(tokens),
+                self.pool.k,
+                self.pool.v,
+                self._row_table_dev,
+                jnp.asarray(write_rows),
+                jnp.asarray(starts),
+            )
         if self.cfg.family == "moe":
             logits, self.pool.k, self.pool.v, counts = out
             self._note_expert_counts(counts)
@@ -1168,43 +1208,45 @@ class Scheduler:
                         rid, "draft", t0, t1, tokens=k_eff[rid] - 1
                     )
                 self.spans.mark(rid, "verify", t1, t2, depth=k_eff[rid])
-        rows = np.asarray(logits)
-        done_slots: list[int] = []
-        for i, rid in lanes:
-            req = self.requests[rid]
-            ke = k_eff[rid]
-            n0 = int(self._lengths[i])
-            accepted = 0
-            for j in range(ke):
-                nxt = self._sample_one(req, rows[i, j])
-                req.output.append(nxt)
-                accepted += 1
-                self._token[i, 0] = nxt
-                if j < ke - 1 and nxt != int(props[rid][j]):
-                    break  # correction token accepted, chain tail rejected
-            self.stats.accepted_tokens += accepted
-            self._lengths[i] = n0 + accepted
-            before = self.pool.blocks_held(rid)
-            self.pool.end_draft(rid, n0 + accepted)
-            if self.pool.blocks_held(rid) != before:
-                self._row_table[i] = self.pool.rows_of(
-                    rid, pad_to=self.s_max
-                )
-                self._table_dirty = True
-            self.speculative.accept(i, n0 + accepted)
-            if len(req.output) >= req.max_new_tokens:
-                done_slots.append(i)
-        # sample pool pressure with every accept settled but finished
-        # requests still resident (the decode-step analog)
-        pool_st = self.pool.stats()
-        self.stats.shared_blocks_peak = max(
-            self.stats.shared_blocks_peak, pool_st.shared_blocks
-        )
-        self.stats.util_samples_any.append(pool_st.utilization)
-        if all(r is not None for r in self.active):
-            self.stats.util_samples.append(pool_st.utilization)
-        for i in done_slots:
-            self._complete(i)
+        with self._phase("logits_fetch"):
+            rows = np.asarray(logits)
+        with self._phase("sample"):
+            done_slots: list[int] = []
+            for i, rid in lanes:
+                req = self.requests[rid]
+                ke = k_eff[rid]
+                n0 = int(self._lengths[i])
+                accepted = 0
+                for j in range(ke):
+                    nxt = self._sample_one(req, rows[i, j])
+                    req.output.append(nxt)
+                    accepted += 1
+                    self._token[i, 0] = nxt
+                    if j < ke - 1 and nxt != int(props[rid][j]):
+                        break  # correction token accepted, chain tail rejected
+                self.stats.accepted_tokens += accepted
+                self._lengths[i] = n0 + accepted
+                before = self.pool.blocks_held(rid)
+                self.pool.end_draft(rid, n0 + accepted)
+                if self.pool.blocks_held(rid) != before:
+                    self._row_table[i] = self.pool.rows_of(
+                        rid, pad_to=self.s_max
+                    )
+                    self._table_dirty = True
+                self.speculative.accept(i, n0 + accepted)
+                if len(req.output) >= req.max_new_tokens:
+                    done_slots.append(i)
+            # sample pool pressure with every accept settled but finished
+            # requests still resident (the decode-step analog)
+            pool_st = self.pool.stats()
+            self.stats.shared_blocks_peak = max(
+                self.stats.shared_blocks_peak, pool_st.shared_blocks
+            )
+            self.stats.util_samples_any.append(pool_st.utilization)
+            if all(r is not None for r in self.active):
+                self.stats.util_samples.append(pool_st.utilization)
+            for i in done_slots:
+                self._complete(i)
 
     # ---------------- main loop ----------------
 
@@ -1212,8 +1254,9 @@ class Scheduler:
         """One scheduler round: drain admissions, advance one chunk of any
         mid-prefill long prompt, then R_F decode steps (speculate-and-
         verify cycles when a drafter is installed)."""
-        while self._admit_one():
-            pass
+        with self._phase("admit"):
+            while self._admit_one():
+                pass
         for i, rid in enumerate(self.active):
             if rid is not None and rid in self._chunk_cursor:
                 self._prefill_one_chunk(i)
@@ -1227,29 +1270,30 @@ class Scheduler:
                 break
             step()
         self.stats.decode_time += time.monotonic() - t0
-        if self.spans is not None and self._decode_open:
-            # close still-running lanes' slices at the round's decode end
-            t = self.spans.now()
-            for rid, (ts, steps) in self._decode_open.items():
-                self.spans.mark(rid, "decode", ts, t, steps=steps)
-            self._decode_open.clear()
-        self.stats.rounds += 1
-        if self.mem_monitor is not None:
-            self.mem_monitor.observe(
-                t=(
-                    self.spans.now()
-                    if self.spans is not None
-                    else float(self.stats.rounds)
-                ),
-                pool=self.pool,
-                evicted_blocks=(
-                    self.prefix_cache.evicted_blocks
-                    if self.prefix_cache is not None
-                    else 0
-                ),
-            )
-        if self.tracker is not None or self.on_round is not None:
-            self._emit_round()
+        with self._phase("round_tail"):
+            if self.spans is not None and self._decode_open:
+                # close still-running lanes' slices at the round's decode end
+                t = self.spans.now()
+                for rid, (ts, steps) in self._decode_open.items():
+                    self.spans.mark(rid, "decode", ts, t, steps=steps)
+                self._decode_open.clear()
+            self.stats.rounds += 1
+            if self.mem_monitor is not None:
+                self.mem_monitor.observe(
+                    t=(
+                        self.spans.now()
+                        if self.spans is not None
+                        else float(self.stats.rounds)
+                    ),
+                    pool=self.pool,
+                    evicted_blocks=(
+                        self.prefix_cache.evicted_blocks
+                        if self.prefix_cache is not None
+                        else 0
+                    ),
+                )
+            if self.tracker is not None or self.on_round is not None:
+                self._emit_round()
         if self.spans is not None:
             self.spans.flush()
 

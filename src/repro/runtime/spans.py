@@ -23,6 +23,24 @@ inside the scheduler/engine and emits one span per lifecycle phase —
 — through ``Tracker.log_spans`` as ``kind="span"`` records, interleaved
 with the round records in the same JSONL file.
 
+The same recorder also times the scheduler round's own phases, which
+belong to no request: ``phase(name)`` opens the profiler annotation
+``serve.<name>`` (on the device trace's clock, in any profile taken)
+and, with a tracker, emits a rid-less ``round.<name>`` span record —
+
+    admit            the admission loop: prefix lookup, adoption/COW,
+                     single-step prefills with their logits fetch
+    prefill_chunk    one chunk of a long prompt, dispatch to first token
+    decode_dispatch  host work before a decode step and its dispatch
+    logits_fetch     the host blocked on a step's logits
+    sample           sampling and completion after the fetch
+    round_tail       per-round bookkeeping after the decode loop
+
+— plus one ``step.scopes`` record per decode program: its HLO
+instruction names mapped to the model's named scopes (``scopes``), so
+a device trace's operations can be put down to the KV sub-layer. The
+request readers below skip every rid-less record.
+
 The decomposition contract (checked by ``validate_trace``, the span
 analogue of ``tracker.replay_summary``): for every completed request,
 its spans tile the closed interval [t_submit, t_done] *exactly* — each
@@ -44,9 +62,12 @@ SRE burn-rate alert shape, here on the virtual clock.
 
 from __future__ import annotations
 
+import contextlib
 import math
 from collections import deque
 from typing import Callable, Iterable
+
+from jax.profiler import TraceAnnotation
 
 # one rounding, at the source: every timestamp the recorder hands out is
 # rounded once to this many decimals (1 ns on the virtual clock), so any
@@ -64,6 +85,13 @@ SPAN_PHASES = (
     "wait",
     "requeue",
 )
+
+
+def phase_annotation(name: str) -> TraceAnnotation:
+    """Only the profiler annotation of round phase ``name``: what a
+    scheduler with no recorder opens. With the profiler off it costs one
+    annotation enter and exit."""
+    return TraceAnnotation("serve." + name)
 
 
 class VirtualClock:
@@ -205,6 +233,45 @@ class SpanRecorder:
         out, self.events = self.events, []
         return out
 
+    # ---------------- round phases ----------------
+
+    def phase(self, name: str, **attrs):
+        """Context manager timing round phase ``name``: the profiler
+        annotation always, and with a tracker a rid-less ``round.<name>``
+        record carrying ``attrs``."""
+        if self.tracker is None:
+            return phase_annotation(name)
+        return self._recorded_phase(name, attrs)
+
+    @contextlib.contextmanager
+    def _recorded_phase(self, name: str, attrs: dict):
+        with phase_annotation(name):
+            t0 = self.now()
+            yield
+            self._buf.append(
+                self._lane_less("round." + name, t0=t0, t1=self.now(),
+                                **attrs)
+            )
+
+    def step_scopes(self, program: str, scopes: dict[str, str]) -> None:
+        """Record which named scope each HLO instruction of compiled step
+        ``program`` belongs to ({instruction name: scope path})."""
+        if self.tracker is None:
+            return
+        self._buf.append(
+            self._lane_less("step.scopes", program=program, scopes=scopes)
+        )
+        self.flush()
+
+    def _lane_less(self, phase: str, **fields) -> dict:
+        rec = {"phase": phase}
+        if self.engine is not None:
+            rec["engine"] = self.engine
+        if self.role is not None:
+            rec["role"] = self.role
+        rec.update(fields)
+        return rec
+
     # ---------------- emission ----------------
 
     def flush(self) -> None:
@@ -219,7 +286,9 @@ class SpanRecorder:
 
 
 def iter_span_records(records: Iterable[dict]) -> list[dict]:
-    return [r for r in records if r.get("kind") == "span"]
+    """The request span records: round phases and scope tables carry no
+    ``rid`` and are left out."""
+    return [r for r in records if r.get("kind") == "span" and "rid" in r]
 
 
 def request_spans(records: Iterable[dict]) -> dict[int, list[dict]]:

@@ -202,6 +202,32 @@ def _run_sched(cfg, params, *, n=5, trk=None, **kw):
     return sched, stats, trk
 
 
+@pytest.mark.parametrize("trace", [False, True])
+def test_serve_builds_a_ledger_only_with_a_trace_to_write(setup, tmp_path,
+                                                          trace):
+    """Without ``--trace-out`` nothing reads the ledger's records, so
+    ``launch.serve`` builds none and the pool snapshots nothing on each
+    mutation; the pressure monitor its summary prints stays."""
+    from repro.launch import serve
+
+    cfg, params, _ = setup
+    argv = ["--smoke", "--requests", "3", "--batch", str(SLOTS),
+            "--max-len", str(MAX_LEN), "--block-tokens", str(BLOCK),
+            "--prompt-len", "8", "--gen-len", "4"]
+    if trace:
+        argv += ["--trace-out", str(tmp_path / "trace.jsonl")]
+    args = serve.build_parser().parse_args(argv)
+    sched = serve.build_pool_engine(cfg, params, args)
+    assert sched.mem_monitor is not None
+    if trace:
+        assert sched.ledger is not None and sched.pool.ledger is sched.ledger
+    else:
+        assert sched.ledger is None and sched.pool.ledger is None
+    out = serve.run_pool_engine(cfg, params, args, sched)
+    assert out["mem"]["observed"] == sched.stats.rounds
+    assert (out["mem_records"] > 0) == trace
+
+
 def test_scheduler_stream_validates_and_replays(setup):
     cfg, params, _ = setup
     sched, stats, trk = _run_sched(cfg, params)

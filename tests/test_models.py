@@ -273,3 +273,68 @@ def test_packed_lm_close_to_dense_ffn():
     toks = jnp.zeros((1, 8), jnp.int32)
     lg, _ = lm.forward(params, cfg, toks)
     assert bool(jnp.isfinite(lg).all())
+
+
+# --------------------------------------------------------------------------
+# named scopes of the paged serving steps
+# --------------------------------------------------------------------------
+
+
+def _paged_step(kind):
+    """(jitted serving step, arguments) of one paged path, at smoke size."""
+    import dataclasses
+
+    from repro.runtime import steps
+    from repro.runtime.kv_pool import KVPool
+
+    arch = "zamba2_2p7b" if kind.startswith("hybrid") else "smollm_360m"
+    cfg = get_smoke_config(arch)
+    if kind == "budgeted":
+        cfg = dataclasses.replace(cfg, w_bits=2)
+    params = lm.init_params(cfg, jax.random.key(0))
+    pool = KVPool.for_slots(cfg, slots=2, max_len=32, block_tokens=4)
+    s_max = pool.max_rows(32)
+    table = jnp.zeros((2, s_max), jnp.int32)
+    lengths = jnp.zeros((2,), jnp.int32)
+    token = jnp.zeros((2, 1), jnp.int32)
+    chunk = (jnp.zeros((1, 8), jnp.int32), pool.k, pool.v, table[:1],
+             jnp.zeros((1, 8), jnp.int32), jnp.int32(0), jnp.int32(7))
+    lane = lm.init_ssm_lane_state(cfg, 2) if arch == "zamba2_2p7b" else None
+    if kind == "decode":
+        return (steps.make_paged_serve_step(cfg),
+                (params, token, pool.k, pool.v, table, lengths))
+    if kind == "budgeted":
+        mask = tuple(i % 2 == 0 for i in range(cfg.n_layers))
+        return (steps.make_budgeted_paged_serve_step(cfg, mask, 2),
+                (params, token, pool.k, pool.v, table, lengths))
+    if kind == "chunk":
+        return steps.make_chunk_prefill_step(cfg), (params, *chunk)
+    if kind == "verify":
+        return (steps.make_verify_step(cfg),
+                (params, jnp.zeros((2, 3), jnp.int32), pool.k, pool.v, table,
+                 jnp.zeros((2, 3), jnp.int32), lengths))
+    if kind == "hybrid_decode":
+        return (steps.make_paged_serve_step(cfg),
+                (params, token, pool.k, pool.v, table, lengths, lane))
+    one = jax.tree.map(lambda v: v[:, :1], lane)
+    return steps.make_hybrid_suffix_prefill_step(cfg), (params, *chunk, one)
+
+
+@pytest.mark.parametrize("kind,program", [
+    ("decode", "jit_step"), ("budgeted", "jit_step"), ("chunk", "jit_step"),
+    ("verify", "jit_step"), ("hybrid_decode", "jit_hybrid_step"),
+    ("hybrid_suffix", "jit_step"),
+])
+def test_paged_steps_name_their_kv_sub_layer(kind, program):
+    """Every paged serving step carries the KV pool write and gather in
+    named scopes of their own, inside ``attention``, beside ``ffn`` and
+    ``logits``, in its op metadata; the lowered program keeps its name
+    (``jit_step``, which the benchmark's program matcher looks for)."""
+    from repro.perf.hlo_analysis import scope_table
+
+    step, args = _paged_step(kind)
+    text = jax.jit(step).lower(*args).as_text(dialect="hlo", debug_info=True)
+    assert text.startswith(f"HloModule {program},")
+    paths = set(scope_table(text, lm.STEP_SCOPES).values())
+    assert {"attention/kv_write", "attention/kv_gather", "ffn",
+            "logits"} <= paths

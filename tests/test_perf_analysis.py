@@ -142,3 +142,35 @@ def test_scan_undercount_demonstrated():
     theirs = float(xla_cost_analysis(compiled).get("flops", 0.0))
     assert ours == n * per_iter, (ours, n * per_iter)
     assert theirs <= per_iter * 2  # XLA counts the body ~once
+
+
+SCOPED = """
+HloModule jit_step
+
+%fused_computation (param_0: f32[16,8], param_1: s32[4]) -> f32[4,8] {
+  %param_0 = f32[16,8]{1,0} parameter(0)
+  %param_1 = s32[4]{0} parameter(1)
+  ROOT %gather.2 = f32[4,8]{1,0} gather(%param_0, %param_1), metadata={op_name="jit(step)/while/body/attention/kv_gather/gather" stack_frame_id=4}
+}
+
+ENTRY %main (p: f32[16,8], x: s32[4]) -> f32[4,8] {
+  %p = f32[16,8]{1,0} parameter(0), metadata={op_name="p"}
+  %x = s32[4]{0} parameter(1), metadata={op_name="x"}
+  %copy.7 = f32[16,8]{0,1} copy(%p)
+  %gather_fusion.1 = f32[4,8]{1,0} fusion(%copy.7, %x), kind=kLoop, calls=%fused_computation, metadata={op_name="jit(step)/while/body/attention/kv_gather/gather" stack_frame_id=4}
+  ROOT %dot.3 = f32[4,8]{1,0} dot(%gather_fusion.1, %p), metadata={op_name="jit(step)/while/body/ffn/dot_general"}
+}
+"""
+
+
+def test_scope_table_maps_instructions_to_named_scopes():
+    from repro.perf.hlo_analysis import scope_table
+
+    table = scope_table(SCOPED, ("attention", "kv_gather", "ffn"))
+    assert table == {
+        "gather.2": "attention/kv_gather",
+        "gather_fusion.1": "attention/kv_gather",
+        "dot.3": "ffn",
+    }
+    # operations of no named scope (parameters, layout copies) are left out
+    assert scope_table(SCOPED, ("logits",)) == {}

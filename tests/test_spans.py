@@ -121,6 +121,67 @@ def test_recorder_without_tracker_keeps_no_buffer():
     rec.flush()  # no tracker: must not raise
 
 
+class _Annotations:
+    """Stands in for ``jax.profiler.TraceAnnotation``: logs each enter
+    and exit by name."""
+
+    def __init__(self):
+        self.log: list[str] = []
+
+    def __call__(self, name):
+        log = self.log
+
+        class _Ann:
+            def __enter__(self):
+                log.append(name)
+
+            def __exit__(self, *exc):
+                log.append("/" + name)
+
+        return _Ann()
+
+
+@pytest.mark.parametrize("tracked", [True, False])
+def test_round_phase_opens_its_annotation_and_records_when_tracked(
+        monkeypatch, tracked):
+    from repro.runtime import spans as spans_mod
+
+    ann = _Annotations()
+    monkeypatch.setattr(spans_mod, "TraceAnnotation", ann)
+    clock = VirtualClock()
+    mem = MemoryTracker()
+    rec = SpanRecorder(clock.now, tracker=mem if tracked else None,
+                       engine=2, role="both")
+    with rec.phase("admit", round=4):
+        clock.advance(0.25)
+    rec.flush()
+    assert ann.log == ["serve.admit", "/serve.admit"]
+    want = [{"kind": "span", "phase": "round.admit", "engine": 2,
+             "role": "both", "t0": 0.0, "t1": 0.25, "round": 4}]
+    assert mem.spans == (want if tracked else [])
+    # a phase is no request span: nothing tiles, nothing is counted
+    assert rec.n_spans == 0 and rec._buf == [] and rec._last == {}
+
+
+def test_request_readers_skip_round_phases_and_scope_tables(setup):
+    """A fleet stream with round phases and the decode program's scope
+    tables decomposes exactly as its request spans alone do."""
+    cfg, params, cost = setup
+    _, _, mem = _run_fleet(cfg, params, cost, n_requests=6, seed=5)
+    recs = _stream(mem)
+    laneless = [r for r in recs if r.get("kind") == "span" and "rid" not in r]
+    phases = {r["phase"] for r in laneless}
+    assert {"round.admit", "round.decode_dispatch", "round.logits_fetch",
+            "round.sample", "round.round_tail", "step.scopes"} <= phases
+    bare = [r for r in recs if r not in laneless]
+    assert validate_trace(recs) == [] == validate_trace(bare)
+    assert request_spans(recs) == request_spans(bare)
+    assert decompose(recs) == decompose(bare)
+    doc = to_trace_events(recs)
+    assert validate_trace_events(doc) == []
+    assert doc == to_trace_events(bare)
+
+
 # ---------------- standalone scheduler, wall clock ----------------
 
 
@@ -151,6 +212,88 @@ def test_standalone_scheduler_wall_clock_spans(setup):
     for spans in groups.values():  # contiguity holds on the wall clock too
         for a, b in zip(spans, spans[1:]):
             assert b["t0"] == a["t1"]
+
+
+def test_scheduler_records_its_round_phases(setup):
+    """A tracked scheduler records one admission and one tail per round,
+    and per decode step its dispatch, logits fetch and sampling, in that
+    order and without overlap, each tagged with its round."""
+    cfg, params, _ = setup
+    rng = np.random.default_rng(2)
+    mem = MemoryTracker()
+    pool = KVPool.for_slots(
+        cfg, slots=SLOTS, max_len=MAX_LEN, block_tokens=BLOCK
+    )
+    sched = Scheduler(
+        cfg, params, pool, slots=SLOTS, max_len=MAX_LEN, token_budget=16,
+        spans=SpanRecorder(time.monotonic, tracker=mem),
+    )
+    sched.submit(rng.integers(0, cfg.vocab, size=(24,)).astype(np.int32), 3)
+    sched.submit(rng.integers(0, cfg.vocab, size=(6,)).astype(np.int32), 4)
+    stats = sched.run()
+    phases = [s for s in mem.spans if s["phase"].startswith("round.")]
+    assert all("rid" not in s for s in phases)
+    count = lambda name: sum(s["phase"] == f"round.{name}" for s in phases)
+    assert count("admit") == count("round_tail") == stats.rounds
+    for name in ("decode_dispatch", "logits_fetch", "sample"):
+        assert count(name) == stats.decode_steps
+    assert count("prefill_chunk") >= 2  # the 24-token prompt, chunked
+    steps = [s for s in phases if s["phase"] in (
+        "round.decode_dispatch", "round.logits_fetch", "round.sample")]
+    for a, b in zip(steps, steps[1:]):
+        assert a["t1"] <= b["t0"] and a["round"] <= b["round"]
+    assert [s["phase"] for s in steps[:3]] == [
+        "round.decode_dispatch", "round.logits_fetch", "round.sample"]
+
+
+def test_untraced_scheduler_opens_only_annotations(setup, monkeypatch):
+    """With no recorder the round phases still reach the profiler."""
+    from repro.runtime import spans as spans_mod
+
+    ann = _Annotations()
+    monkeypatch.setattr(spans_mod, "TraceAnnotation", ann)
+    cfg, params, _ = setup
+    rng = np.random.default_rng(3)
+    pool = KVPool.for_slots(
+        cfg, slots=SLOTS, max_len=MAX_LEN, block_tokens=BLOCK
+    )
+    sched = Scheduler(cfg, params, pool, slots=SLOTS, max_len=MAX_LEN,
+                      token_budget=16)
+    sched.submit(rng.integers(0, cfg.vocab, size=(24,)).astype(np.int32), 3)
+    sched.run()
+    opened = {n for n in ann.log if not n.startswith("/")}
+    assert opened == {f"serve.{p}" for p in (
+        "admit", "prefill_chunk", "decode_dispatch", "logits_fetch", "sample",
+        "round_tail")}
+    assert ann.log.count("serve.admit") == sched.stats.rounds
+
+
+def test_attaching_a_tracked_recorder_records_the_decode_scopes(setup):
+    """Attached to a warm scheduler (as a traced serving run attaches it
+    after warm-up), a tracked recorder gets the decode program's scope
+    table at once, and nothing compiles for it."""
+    cfg, params, _ = setup
+    rng = np.random.default_rng(6)
+    pool = KVPool.for_slots(
+        cfg, slots=SLOTS, max_len=MAX_LEN, block_tokens=BLOCK
+    )
+    sched = Scheduler(cfg, params, pool, slots=SLOTS, max_len=MAX_LEN)
+    sched.submit(rng.integers(0, cfg.vocab, size=(6,)).astype(np.int32), 3)
+    sched.run()
+    compiles = []
+    jax.monitoring.register_event_duration_secs_listener(
+        lambda key, _s, **_kw: compiles.append(key)
+    )
+    mem = MemoryTracker()
+    sched.spans = SpanRecorder(time.monotonic, tracker=mem)
+    assert "/jax/core/compile/backend_compile_duration" not in compiles
+    (rec,) = mem.spans
+    assert rec["phase"] == "step.scopes" and rec["program"] == "decode"
+    assert {"attention/kv_gather", "attention/kv_write", "ffn",
+            "logits"} <= set(rec["scopes"].values())
+    # an untracked recorder gets no table
+    sched.spans = SpanRecorder(time.monotonic)
+    assert len(mem.spans) == 1
 
 
 def test_scheduler_drain_aborts_open_timelines(setup):
