@@ -74,7 +74,7 @@ VMEM_BUDGET_MIB = 28.0
 # carried through 32 layers: 0.007-0.008 on a v5e. The controls printed
 # beside it (attending another request's rows: about 0.7; dropping the
 # newest row: 0.05-0.1) must exceed it, or the check could not see a
-# wrong row table or length.
+# wrong block table or length.
 A_TOL = 0.025
 # Phase B tolerance, same form: streamed layers multiply exact {-1,0,1}
 # weights and scale in f32, resident layers in bf16, so the two runs
@@ -170,7 +170,7 @@ def phase_a(cfg, argv=SERVE_ARGV, probes=PROBES) -> None:
             want = ref[pos]
             scale = spread(want)
             got = max_diff(rows[rid, n], want) / scale
-            # what a wrong row table gives: the same last token over
+            # what a wrong block table gives: the same last token over
             # another request's rows
             ctx = np.concatenate([other[:pos], seq[pos : pos + 1]])
             wrong_rows = max_diff(last_logits(ctx), want) / scale
@@ -229,7 +229,7 @@ def phase_b(
             jnp.zeros((b, 1), jnp.int32),
             sched.pool.k,
             sched.pool.v,
-            jnp.zeros((b, sched.s_max), jnp.int32),
+            jnp.zeros((b, sched.n_table), jnp.int32),
             jnp.zeros((b,), jnp.int32),
         ).as_text()
         require("tpu_custom_call" in hlo, "budgeted step runs no kernel")

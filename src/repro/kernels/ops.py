@@ -122,6 +122,32 @@ def stream_matmul(
     return out[:m, :n].reshape(*lead, n)
 
 
+def paged_decode_runs_kernel(hd: int, pool: jnp.ndarray) -> bool:
+    """Whether the decode step's KV sub-layer runs ``paged_decode``'s
+    kernel: on the TPU backend, for a block tile the kernel covers (else
+    the reference formulation runs, gathering every lane's whole table;
+    interpret-mode DMA emulation is exercised by the kernel equivalence
+    tests)."""
+    if _on_cpu():
+        return False
+    from repro.kernels import paged_attention as _pa
+
+    return _pa.supports(hd, pool.shape[-2:])
+
+
+def paged_decode(q, k_new, v_new, pool_k, pool_v, layer, block_table,
+                 lengths, *, n_kv: int, window: int = 0):
+    """The Pallas paged decode kernel (``kernels.paged_attention``): each
+    lane's new K/V row written in place and its live blocks attended
+    over. Returns (attention (B, 1, Hq, D), pool_k, pool_v)."""
+    from repro.kernels import paged_attention as _pa
+
+    return _pa.paged_decode(
+        q, k_new, v_new, pool_k, pool_v, layer, block_table, lengths,
+        n_kv=n_kv, window=window,
+    )
+
+
 def mvau(
     x: jnp.ndarray,
     packed_w: jnp.ndarray,

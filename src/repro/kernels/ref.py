@@ -123,3 +123,21 @@ def flash_attention_ref(
     p = jnp.where(jnp.isnan(p), 0.0, p)
     o = jnp.einsum("bhgqk,bkhd->bqhgd", p, v.astype(jnp.float32))
     return o.reshape(b, sq, hq, d).astype(q.dtype)
+
+
+def paged_decode_ref(
+    q, k_new, v_new, pool_k, pool_v, layer, block_table, lengths, *,
+    n_kv: int, window: int = 0,
+):
+    """Oracle for ``paged_decode``: the new rows written through
+    ``attention.write_tokens``, then every lane's whole block table
+    gathered (``attention.paged_decode_attention_ref``)."""
+    from repro.models.attention import paged_decode_attention_ref, write_tokens
+
+    pool_k = write_tokens(pool_k, layer, block_table, lengths, k_new[:, None])
+    pool_v = write_tokens(pool_v, layer, block_table, lengths, v_new[:, None])
+    o = paged_decode_attention_ref(
+        q, pool_k, pool_v, layer, block_table, lengths + 1, n_kv=n_kv,
+        window=window,
+    )
+    return o, pool_k, pool_v

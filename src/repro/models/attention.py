@@ -228,6 +228,121 @@ def chunk_attention(
     return out.reshape(b, c, hq, d).astype(q.dtype)
 
 
+# --------------------------------------------------------------------------
+# The paged KV pool's block-contiguous layout
+# --------------------------------------------------------------------------
+
+SCRATCH_BLOCK = 0  # pool block 0 is never allocated: unused table entries
+
+
+def pool_tile(n_kv: int, block_tokens: int, hd: int) -> tuple[int, int]:
+    """(rows, width) of one pool block. A block's ``block_tokens`` tokens x
+    ``n_kv`` heads x ``hd`` values are stored head-major (head, token,
+    dim); each head's values fill rows of 128 lanes, the TPU's vector
+    width, when they divide into them (16 x 64 -> 8 rows), else one row
+    of their own."""
+    per_head = block_tokens * hd
+    width = 128 if per_head % 128 == 0 else per_head
+    return n_kv * per_head // width, width
+
+
+def tiles_to_tokens(tiles: jnp.ndarray, n_kv: int, hd: int) -> jnp.ndarray:
+    """(..., nb, rows, width) blocks -> (..., nb * T, n_kv, hd) rows in
+    position order."""
+    *lead, nb, r, w = tiles.shape
+    t = r * w // (n_kv * hd)
+    x = tiles.reshape(*lead, nb, n_kv, t, hd)
+    return jnp.swapaxes(x, -3, -2).reshape(*lead, nb * t, n_kv, hd)
+
+
+def tokens_to_tiles(
+    rows: jnp.ndarray, block_tokens: int, tile: tuple[int, int]
+) -> jnp.ndarray:
+    """Inverse of ``tiles_to_tokens``: (..., S, n_kv, hd) with S a multiple
+    of ``block_tokens`` -> (..., S / block_tokens, *tile)."""
+    *lead, s, n_kv, hd = rows.shape
+    x = rows.reshape(*lead, s // block_tokens, block_tokens, n_kv, hd)
+    return jnp.swapaxes(x, -3, -2).reshape(*lead, s // block_tokens, *tile)
+
+
+def live_block_span(kv_len, block_tokens: int, window: int = 0):
+    """(first, end): the block-table entries ``[first, end)`` holding a
+    position that a query at ``kv_len - 1`` attends to, for numpy or
+    traced ``kv_len`` (the decode kernel visits these and no others)."""
+    end = (kv_len + block_tokens - 1) // block_tokens
+    if not window:
+        return end * 0, end
+    return (kv_len - window).clip(0) // block_tokens, end
+
+
+def decode_window(cfg) -> int:
+    """The window a paged decode query attends within: the config's
+    sliding window, but none in the hybrid family, whose shared attention
+    block attends over every position."""
+    return 0 if cfg.family == "hybrid" else cfg.sliding_window
+
+
+def gather_tokens(
+    pool: jnp.ndarray, layer, block_table: jnp.ndarray, n_kv: int, hd: int
+) -> jnp.ndarray:
+    """Every lane's whole block table of one layer's pool, as rows:
+    pool (L, n_blocks, rows, width), block_table (B, nb) ->
+    (B, nb * T, n_kv, hd), row i holding position i."""
+    return tiles_to_tokens(pool[layer, block_table], n_kv, hd)
+
+
+def write_tokens(
+    pool: jnp.ndarray,
+    layer,
+    block_table: jnp.ndarray,
+    starts: jnp.ndarray,
+    vals: jnp.ndarray,
+) -> jnp.ndarray:
+    """Write lane b's ``vals[b]`` (C, n_kv, hd) at its positions
+    ``starts[b] ..`` of one layer's pool, in place: the table blocks the
+    chunk touches are read, patched and written back whole. Positions
+    past the table land in the scratch block. A lane writes only at and
+    past its length, which lie in blocks of its own."""
+    b, c, n_kv, hd = vals.shape
+    tile = pool.shape[-2:]
+    t = tile[0] * tile[1] // (n_kv * hd)  # tokens per block
+    nb = block_table.shape[1]
+    j = starts[:, None] // t + jnp.arange((c + t - 2) // t + 1)[None]
+    ids = jnp.where(
+        j < nb,
+        jnp.take_along_axis(block_table, jnp.minimum(j, nb - 1), axis=1),
+        SCRATCH_BLOCK,
+    )
+    cur = tiles_to_tokens(pool[layer, ids], n_kv, hd)
+    new = jax.vmap(
+        lambda x, v, o: jax.lax.dynamic_update_slice_in_dim(x, v, o, 0)
+    )(cur, vals.astype(pool.dtype), starts % t)
+    return pool.at[layer, ids].set(tokens_to_tiles(new, t, tile))
+
+
+def paged_decode_attention_ref(
+    q: jnp.ndarray,
+    pool_k: jnp.ndarray,
+    pool_v: jnp.ndarray,
+    layer,
+    block_table: jnp.ndarray,
+    kv_lens: jnp.ndarray,
+    *,
+    n_kv: int,
+    window: int = 0,
+) -> jnp.ndarray:
+    """One-token attention over a paged pool, by gathering every lane's
+    whole block table into rows and taking the masked softmax of
+    ``decode_attention``: the reference of ``kernels.paged_attention``.
+    q: (B, 1, Hq, D); kv_lens: (B,) positions each lane attends to."""
+    d = q.shape[-1]
+    kg = gather_tokens(pool_k, layer, block_table, n_kv, d)
+    vg = gather_tokens(pool_v, layer, block_table, n_kv, d)
+    o = decode_attention(q, kg, vg, kv_lens[:, None], window=window)
+    # a lane with no position to attend to reads nothing: zeros
+    return jnp.where(kv_lens[:, None, None, None] > 0, o, 0).astype(q.dtype)
+
+
 def cache_insert(
     cache: jnp.ndarray, new: jnp.ndarray, pos: jnp.ndarray
 ) -> jnp.ndarray:

@@ -825,15 +825,49 @@ def prefill_with_cache(
 STEP_SCOPES = ("attention", "kv_write", "kv_gather", "ffn", "logits")
 
 
-def _paged_kv(pk, pv, write_rows, k, v, row_table):
-    """Scatter new K/V rows into one layer's pool, then gather every
-    lane's rows through ``row_table``: (new pk, new pv, gathered k,
-    gathered v)."""
+def _paged_kv(pk, pv, layer, block_table, starts, k, v):
+    """Write each lane's new K/V rows ((B, C, n_kv, hd), lane b's at its
+    positions ``starts[b]..``) into layer ``layer`` of the block pools,
+    then gather every lane's whole block table as rows: (new pk, new pv,
+    gathered k, gathered v), the gathered rows (B, S_max, n_kv, hd)."""
+    n_kv, hd = k.shape[-2:]
     with jax.named_scope("kv_write"):
-        pk = pk.at[write_rows].set(k)
-        pv = pv.at[write_rows].set(v)
+        pk = attn.write_tokens(pk, layer, block_table, starts, k)
+        pv = attn.write_tokens(pv, layer, block_table, starts, v)
     with jax.named_scope("kv_gather"):
-        return pk, pv, pk[row_table], pv[row_table]
+        return (
+            pk, pv,
+            attn.gather_tokens(pk, layer, block_table, n_kv, hd),
+            attn.gather_tokens(pv, layer, block_table, n_kv, hd),
+        )
+
+
+def _paged_decode_kv(pk, pv, layer, block_table, lengths, q, k, v, window):
+    """The decode step's KV sub-layer: each lane's new K/V row written at
+    position ``lengths[b]`` of layer ``layer``, and attention over the
+    lane's positions 0..lengths[b]: (new pk, new pv, attention output
+    (B, 1, Hq, hd)). On the chip one kernel does both and reads only the
+    live blocks (``kernels.paged_attention``); elsewhere the row is
+    written block-wise and every lane's whole table gathered."""
+    from repro.kernels import ops
+
+    n_kv = k.shape[-2]
+    if ops.paged_decode_runs_kernel(q.shape[-1], pk):
+        with jax.named_scope("kv_gather"):
+            o, pk, pv = ops.paged_decode(
+                q, k[:, 0], v[:, 0], pk, pv, layer, block_table, lengths,
+                n_kv=n_kv, window=window,
+            )
+        return pk, pv, o
+    with jax.named_scope("kv_write"):
+        pk = attn.write_tokens(pk, layer, block_table, lengths, k)
+        pv = attn.write_tokens(pv, layer, block_table, lengths, v)
+    with jax.named_scope("kv_gather"):
+        o = attn.paged_decode_attention_ref(
+            q, pk, pv, layer, block_table, lengths + 1, n_kv=n_kv,
+            window=window,
+        )
+    return pk, pv, o
 
 
 def _scoped_logits(params, cfg: ModelConfig, x, last_idx=None):
@@ -853,21 +887,23 @@ def decode_step_paged(
     token: jnp.ndarray,
     pool_k: jnp.ndarray,
     pool_v: jnp.ndarray,
-    row_table: jnp.ndarray,
+    block_table: jnp.ndarray,
     lengths: jnp.ndarray,
     *,
     stream_mask: jnp.ndarray | None = None,
     stream_depth: int = 2,
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """One serving step against a shared row-addressed KV pool.
+    """One serving step against a shared block-addressed KV pool.
 
     token: (B, 1) next token per decode lane; pool_k/pool_v:
-    (L, R, n_kv, hd) physical pools; row_table: (B, S_max) physical row
-    index of each lane's logical cache position (scratch-row padded);
-    lengths: (B,) tokens already held per lane. The new token's K/V row is
-    scattered to ``row_table[b, lengths[b]]``, then each lane attends over
-    its gathered rows with per-lane positions (no lockstep shared length —
-    lanes at different depths coexist in one batched step).
+    (L, n_blocks, rows, width) physical pools of block tiles
+    (``attention.pool_tile``); block_table: (B, nb) physical block of each
+    lane's logical block (scratch-block padded); lengths: (B,) tokens
+    already held per lane. The new token's K/V row is written at position
+    ``lengths[b]`` through the lane's table, then each lane attends over
+    its own live blocks with per-lane positions (no lockstep shared
+    length — lanes at different depths coexist in one batched step). The
+    pools ride the layer scan as its carry and are updated in place.
 
     ``stream_mask`` turns on the budgeted weight-residency path
     (``runtime.residency``). For the dense-FFN families it is (L,) bool:
@@ -887,26 +923,20 @@ def decode_step_paged(
     moe = cfg.family == "moe"
     x = embed(token, params["embed"], _dt(cfg))
     b = x.shape[0]
-    s_max = row_table.shape[1]
     pos_b = lengths[:, None]  # (B, 1) position of the incoming token
-    write_rows = jnp.take_along_axis(
-        row_table, jnp.clip(lengths, 0, s_max - 1)[:, None], axis=1
-    )[:, 0]
 
-    def layer_fn(carry, lp_kv):
-        x, aux = carry
+    def layer_fn(carry, xs):
+        x, aux, pk, pv = carry
         if stream_mask is None:
-            lp, pk, pv = lp_kv  # pk/pv: (R, n_kv, hd) one layer's pool
+            lp, layer = xs
             streamed = None
         else:
-            lp, pk, pv, streamed = lp_kv
+            lp, layer, streamed = xs
         with jax.named_scope("attention"):
             q, k, v = _decode_qkv(lp, cfg, x, pos_b)
-            pk, pv, kg, vg = _paged_kv(
-                pk, pv, write_rows, k[:, 0], v[:, 0], row_table
-            )
-            o = attn.decode_attention(
-                q, kg, vg, (lengths + 1)[:, None], window=cfg.sliding_window
+            pk, pv, o = _paged_decode_kv(
+                pk, pv, layer, block_table, lengths, q, k, v,
+                attn.decode_window(cfg),
             )
             x = x + dense(o.reshape(b, 1, -1), lp["wo"])
         with jax.named_scope("ffn"):
@@ -925,21 +955,57 @@ def decode_step_paged(
                     x,
                 )
         if moe:
-            return (x, aux), (pk, pv, counts)
-        return (x, aux + a), (pk, pv)
+            return (x, aux, pk, pv), counts
+        return (x, aux + a, pk, pv), None
 
-    xs = (params["layers"], pool_k, pool_v)
+    xs = (params["layers"], jnp.arange(cfg.n_layers))
     if stream_mask is not None:
         xs = xs + (stream_mask,)
-    (x, _), outs = jax.lax.scan(
-        layer_fn, (x, jnp.zeros((), jnp.float32)), xs
+    (x, _, pks, pvs), counts = jax.lax.scan(
+        layer_fn, (x, jnp.zeros((), jnp.float32), pool_k, pool_v), xs
     )
     lg = _scoped_logits(params, cfg, x)
     if moe:
-        pks, pvs, counts = outs
         return lg, pks, pvs, counts
-    pks, pvs = outs
     return lg, pks, pvs
+
+
+def _chunk_layers(params, cfg: ModelConfig, x, pool_k, pool_v, block_table,
+                  starts, positions):
+    """The layer scan shared by chunk prefill and draft verification:
+    each lane's C tokens at ``positions`` (its rows written from
+    ``starts[b]``) attend causally over its gathered block table. Returns
+    (x, new pool_k, new pool_v, moe expert-load tally or None)."""
+    moe = cfg.family == "moe"
+    b, c, _ = x.shape
+
+    def layer_fn(carry, xs):
+        x, aux, pk, pv = carry
+        lp, layer = xs
+        with jax.named_scope("attention"):
+            q, k, v = _qkv(lp, cfg, x, positions)
+            pk, pv, kg, vg = _paged_kv(
+                pk, pv, layer, block_table, starts, k, v
+            )
+            # gathered rows sit at logical positions 0..S_max-1; rows past
+            # each query (scratch padding included) are masked by causality
+            o = attn.chunk_attention(
+                q, kg, vg, positions, window=cfg.sliding_window
+            )
+            x = x + dense(o.reshape(b, c, -1), lp["wo"])
+        with jax.named_scope("ffn"):
+            if moe:
+                x, counts = _ffn_block(lp, cfg, x, dropless=True)
+                return (x, aux, pk, pv), counts
+            x, a = _ffn_block(lp, cfg, x)
+        return (x, aux + a, pk, pv), None
+
+    (x, _, pks, pvs), counts = jax.lax.scan(
+        layer_fn,
+        (x, jnp.zeros((), jnp.float32), pool_k, pool_v),
+        (params["layers"], jnp.arange(cfg.n_layers)),
+    )
+    return x, pks, pvs, counts
 
 
 def prefill_chunk_paged(
@@ -948,8 +1014,7 @@ def prefill_chunk_paged(
     tokens: jnp.ndarray,
     pool_k: jnp.ndarray,
     pool_v: jnp.ndarray,
-    row_table: jnp.ndarray,
-    write_rows: jnp.ndarray,
+    block_table: jnp.ndarray,
     start: jnp.ndarray,
     last_idx: jnp.ndarray,
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
@@ -958,20 +1023,21 @@ def prefill_chunk_paged(
     Chunked prefill (ROADMAP): a prompt longer than the scheduler's
     admission token budget is split across rounds instead of monopolizing
     one round with a single huge prefill step. Each chunk attends over the
-    request's *already-pooled* prefix (gathered through ``row_table``)
+    request's *already-pooled* prefix (gathered through ``block_table``)
     plus itself, causally — flash attention with ``q_offset = start`` —
-    and scatters its own K/V rows into the pool. ``start`` doubles as the
+    and writes its own K/V rows into the pool. ``start`` doubles as the
     matched-prefix offset of a prefix-cache hit: the warm path prefills
     only the unmatched suffix, attending over the adopted shared blocks
     exactly as it would over its own earlier chunks.
 
-    tokens: (B, C) chunk tokens, right-padded; write_rows: (B, C) physical
-    pool row per chunk token (scratch row for padding); row_table:
-    (B, S_max) the request's full row table; start: () position of the
-    chunk's first token; last_idx: () in-chunk index of the prompt's last
-    token (only meaningful on the final chunk). Attention-KV families
-    only — moe included: the dropless per-token dispatch makes a chunk
-    boundary invisible to routing, so chunked == single-shot exactly.
+    tokens: (B, C) chunk tokens, right-padded; block_table: (B, nb) the
+    request's block table; start: () position of the chunk's first token
+    (the chunk's rows are written at positions start.., the padding's
+    past the prompt, where nothing valid lies yet); last_idx: () in-chunk
+    index of the prompt's last token (only meaningful on the final
+    chunk). Attention-KV families only — moe included: the dropless
+    per-token dispatch makes a chunk boundary invisible to routing, so
+    chunked == single-shot exactly.
 
     Returns (logits at last_idx (B, 1, V), new pool_k, new pool_v); the
     moe family appends a per-layer expert-load tally (L, E).
@@ -980,42 +1046,16 @@ def prefill_chunk_paged(
         raise ValueError(
             f"prefill_chunk_paged: unsupported family {cfg.family}"
         )
-    moe = cfg.family == "moe"
     x = embed(tokens, params["embed"], _dt(cfg))
     b, c, _ = x.shape
     positions = start + jnp.arange(c)[None, :]  # (1, C) broadcast over B
-
-    def layer_fn(carry, lp_kv):
-        x, aux = carry
-        lp, pk, pv = lp_kv
-        with jax.named_scope("attention"):
-            q, k, v = _qkv(lp, cfg, x, positions)
-            pk, pv, kg, vg = _paged_kv(pk, pv, write_rows, k, v, row_table)
-            # gathered rows sit at logical positions 0..S_max-1; rows past
-            # the chunk (scratch padding included) are masked by causality
-            o = attn.chunk_attention(
-                q, kg, vg, positions, window=cfg.sliding_window
-            )
-            x = x + dense(o.reshape(b, c, -1), lp["wo"])
-        with jax.named_scope("ffn"):
-            if moe:
-                x, counts = _ffn_block(lp, cfg, x, dropless=True)
-            else:
-                x, a = _ffn_block(lp, cfg, x)
-        if moe:
-            return (x, aux), (pk, pv, counts)
-        return (x, aux + a), (pk, pv)
-
-    (x, _), outs = jax.lax.scan(
-        layer_fn,
-        (x, jnp.zeros((), jnp.float32)),
-        (params["layers"], pool_k, pool_v),
+    x, pks, pvs, counts = _chunk_layers(
+        params, cfg, x, pool_k, pool_v, block_table,
+        jnp.full((b,), start, jnp.int32), positions,
     )
     lg = _scoped_logits(params, cfg, x, last_idx)
-    if moe:
-        pks, pvs, counts = outs
+    if cfg.family == "moe":
         return lg, pks, pvs, counts
-    pks, pvs = outs
     return lg, pks, pvs
 
 
@@ -1025,8 +1065,7 @@ def verify_chunk_paged(
     tokens: jnp.ndarray,
     pool_k: jnp.ndarray,
     pool_v: jnp.ndarray,
-    row_table: jnp.ndarray,
-    write_rows: jnp.ndarray,
+    block_table: jnp.ndarray,
     starts: jnp.ndarray,
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Score a depth-C draft chain per lane against the shared KV pool.
@@ -1038,13 +1077,13 @@ def verify_chunk_paged(
     generalised two ways: ``starts`` is per-lane (B,) — decode lanes sit
     at different depths — and the full (B, C, V) logits come back, because
     longest-accepted-prefix selection needs the distribution at every
-    draft position, not just the last. K/V rows for the fed chain scatter
-    into the lanes' own (private, refcounted) blocks; rows past a lane's
-    accepted prefix are dead weight the next chain overwrites, which is
-    what makes rejection rollback free.
+    draft position, not just the last. K/V rows for the fed chain land in
+    the lanes' own (private, refcounted) blocks at positions starts[b]..;
+    rows past a lane's accepted prefix are dead weight the next chain
+    overwrites, which is what makes rejection rollback free.
 
-    tokens: (B, C) draft chains, right-padded; write_rows: (B, C) physical
-    pool row per chain token (scratch row for padding); starts: (B,)
+    tokens: (B, C) draft chains, right-padded (a padded token's row lands
+    past the lane's chain, where nothing valid lies); starts: (B,)
     position of each lane's first fed token. Attention-KV families only —
     moe included (dropless dispatch is chunk-invariant); the moe family
     appends a per-layer expert-load tally (L, E).
@@ -1053,40 +1092,15 @@ def verify_chunk_paged(
         raise ValueError(
             f"verify_chunk_paged: unsupported family {cfg.family}"
         )
-    moe = cfg.family == "moe"
     x = embed(tokens, params["embed"], _dt(cfg))
-    b, c, _ = x.shape
+    c = x.shape[1]
     positions = starts[:, None] + jnp.arange(c)[None, :]  # (B, C)
-
-    def layer_fn(carry, lp_kv):
-        x, aux = carry
-        lp, pk, pv = lp_kv
-        with jax.named_scope("attention"):
-            q, k, v = _qkv(lp, cfg, x, positions)
-            pk, pv, kg, vg = _paged_kv(pk, pv, write_rows, k, v, row_table)
-            o = attn.chunk_attention(
-                q, kg, vg, positions, window=cfg.sliding_window
-            )
-            x = x + dense(o.reshape(b, c, -1), lp["wo"])
-        with jax.named_scope("ffn"):
-            if moe:
-                x, counts = _ffn_block(lp, cfg, x, dropless=True)
-            else:
-                x, a = _ffn_block(lp, cfg, x)
-        if moe:
-            return (x, aux), (pk, pv, counts)
-        return (x, aux + a), (pk, pv)
-
-    (x, _), outs = jax.lax.scan(
-        layer_fn,
-        (x, jnp.zeros((), jnp.float32)),
-        (params["layers"], pool_k, pool_v),
+    x, pks, pvs, counts = _chunk_layers(
+        params, cfg, x, pool_k, pool_v, block_table, starts, positions
     )
     lg = _scoped_logits(params, cfg, x)
-    if moe:
-        pks, pvs, counts = outs
+    if cfg.family == "moe":
         return lg, pks, pvs, counts
-    pks, pvs = outs
     return lg, pks, pvs
 
 
@@ -1176,18 +1190,19 @@ def decode_step_paged_hybrid(
     token: jnp.ndarray,
     pool_k: jnp.ndarray,
     pool_v: jnp.ndarray,
-    row_table: jnp.ndarray,
+    block_table: jnp.ndarray,
     lengths: jnp.ndarray,
     lane_state: dict,
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, dict]:
     """``decode_step_paged`` for the hybrid family.
 
-    The shared attention block of each super-block scatters/gathers its
-    KV rows through the pool (pool_k/pool_v are (n_super, R, n_kv, hd),
-    addressed by the same per-lane ``row_table``/``lengths`` as the
-    attention families), while the SSM recurrence advances the resident
-    per-lane ``lane_state`` (leaves (L, B, ...)). Returns
-    (logits (B, 1, V), new pool_k, new pool_v, new lane_state).
+    The shared attention block of each super-block writes its KV row and
+    attends over the lane's live blocks of the pool (pool_k/pool_v are
+    (n_super, n_blocks, rows, width), addressed by the same per-lane
+    ``block_table``/``lengths`` as the attention families), while the SSM
+    recurrence advances the resident per-lane ``lane_state`` (leaves
+    (L, B, ...)). Returns (logits (B, 1, V), new pool_k, new pool_v, new
+    lane_state).
     """
     if cfg.family != "hybrid":
         raise ValueError(
@@ -1195,11 +1210,7 @@ def decode_step_paged_hybrid(
         )
     x = embed(token, params["embed"], _dt(cfg))
     b = x.shape[0]
-    s_max = row_table.shape[1]
     pos_b = lengths[:, None]
-    write_rows = jnp.take_along_axis(
-        row_table, jnp.clip(lengths, 0, s_max - 1)[:, None], axis=1
-    )[:, 0]
     every = cfg.hybrid_attn_every
     n_super = cfg.n_layers // every
     shaped = jax.tree.map(
@@ -1214,8 +1225,9 @@ def decode_step_paged_hybrid(
     )
     shared = params["shared"]
 
-    def super_block(x, inp):
-        lps, (sts, cxs, cbs, ccs), pk, pv = inp
+    def super_block(carry, inp):
+        x, pk, pv = carry
+        lps, (sts, cxs, cbs, ccs), layer = inp
 
         def inner(x, lp_state):
             lp, st, cx, cb, cc = lp_state
@@ -1227,17 +1239,18 @@ def decode_step_paged_hybrid(
         x, new_states = jax.lax.scan(inner, x, (lps, sts, cxs, cbs, ccs))
         with jax.named_scope("attention"):
             q, k, v = _decode_qkv(shared, cfg, x, pos_b)
-            pk, pv, kg, vg = _paged_kv(
-                pk, pv, write_rows, k[:, 0], v[:, 0], row_table
+            pk, pv, o = _paged_decode_kv(
+                pk, pv, layer, block_table, lengths, q, k, v,
+                attn.decode_window(cfg),
             )
-            o = attn.decode_attention(q, kg, vg, (lengths + 1)[:, None])
             x = x + dense(o.reshape(b, 1, -1), shared["wo"])
         with jax.named_scope("ffn"):
             x, _ = _ffn_block(shared, cfg, x)
-        return x, (new_states, pk, pv)
+        return (x, pk, pv), new_states
 
-    x, (new_states, pks, pvs) = jax.lax.scan(
-        super_block, x, (shaped, states, pool_k, pool_v)
+    (x, pks, pvs), new_states = jax.lax.scan(
+        super_block, (x, pool_k, pool_v),
+        (shaped, states, jnp.arange(n_super)),
     )
     sts, cxs, cbs, ccs = new_states
     merge = lambda v: v.reshape((cfg.n_layers,) + v.shape[2:])
@@ -1254,8 +1267,7 @@ def prefill_suffix_paged_hybrid(
     tokens: jnp.ndarray,
     pool_k: jnp.ndarray,
     pool_v: jnp.ndarray,
-    row_table: jnp.ndarray,
-    write_rows: jnp.ndarray,
+    block_table: jnp.ndarray,
     start: jnp.ndarray,
     last_idx: jnp.ndarray,
     lane_state: dict,
@@ -1264,7 +1276,7 @@ def prefill_suffix_paged_hybrid(
 
     The prefix-cache warm path for zamba2: positions ``0..start-1`` were
     served by a cached prefix — their shared-attention KV rows sit in the
-    pool (gathered through ``row_table``) and the SSM recurrence resumes
+    pool (gathered through ``block_table``) and the SSM recurrence resumes
     from ``lane_state``, the anchor snapshot taken when the prefix was
     committed (leaves shaped (L, B, ...) as in ``init_ssm_lane_state``).
     The suffix's SSD scan seeds ``ssd_chunked`` with the carried state
@@ -1273,11 +1285,11 @@ def prefill_suffix_paged_hybrid(
     also the machinery chunked hybrid prefill needs (SSD state carried
     across chunks).
 
-    tokens: (B, C) **unpadded** suffix (hybrid prompts never pad);
-    write_rows: (B, C) physical pool row per suffix token; start: ()
-    position of the suffix's first token; last_idx: () in-suffix index
-    of the prompt's last token. Returns (logits at last_idx (B, 1, V),
-    new pool_k, new pool_v, new lane_state).
+    tokens: (B, C) **unpadded** suffix (hybrid prompts never pad), its
+    rows written at positions ``start..``; start: () position of the
+    suffix's first token; last_idx: () in-suffix index of the prompt's
+    last token. Returns (logits at last_idx (B, 1, V), new pool_k, new
+    pool_v, new lane_state).
     """
     if cfg.family != "hybrid":
         raise ValueError(
@@ -1286,6 +1298,7 @@ def prefill_suffix_paged_hybrid(
     x = embed(tokens, params["embed"], _dt(cfg))
     b, c, _ = x.shape
     positions = start + jnp.arange(c)[None, :]
+    starts = jnp.full((b,), start, jnp.int32)
     every = cfg.hybrid_attn_every
     n_super = cfg.n_layers // every
     shaped = jax.tree.map(
@@ -1300,8 +1313,9 @@ def prefill_suffix_paged_hybrid(
     )
     shared = params["shared"]
 
-    def super_block(x, inp):
-        lps, (sts, cxs, cbs, ccs), pk, pv = inp
+    def super_block(carry, inp):
+        x, pk, pv = carry
+        lps, (sts, cxs, cbs, ccs), layer = inp
 
         def inner(x, lp_state):
             lp, st, cx, cb, cc = lp_state
@@ -1313,15 +1327,18 @@ def prefill_suffix_paged_hybrid(
         x, new_states = jax.lax.scan(inner, x, (lps, sts, cxs, cbs, ccs))
         with jax.named_scope("attention"):
             q, k, v = _qkv(shared, cfg, x, positions)
-            pk, pv, kg, vg = _paged_kv(pk, pv, write_rows, k, v, row_table)
+            pk, pv, kg, vg = _paged_kv(
+                pk, pv, layer, block_table, starts, k, v
+            )
             o = attn.chunk_attention(q, kg, vg, positions)
             x = x + dense(o.reshape(b, c, -1), shared["wo"])
         with jax.named_scope("ffn"):
             x, _ = _ffn_block(shared, cfg, x)
-        return x, (new_states, pk, pv)
+        return (x, pk, pv), new_states
 
-    x, (new_states, pks, pvs) = jax.lax.scan(
-        super_block, x, (shaped, states, pool_k, pool_v)
+    (x, pks, pvs), new_states = jax.lax.scan(
+        super_block, (x, pool_k, pool_v),
+        (shaped, states, jnp.arange(n_super)),
     )
     sts, cxs, cbs, ccs = new_states
     merge = lambda v: v.reshape((cfg.n_layers,) + v.shape[2:])

@@ -33,6 +33,7 @@ block-size sweep and the tail-sharing lower bound.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from collections import Counter
 from typing import Callable
 
@@ -43,18 +44,28 @@ import numpy as np
 from repro.core.buffers import WeightBuffer
 from repro.core.packing import PackItem, baseline_packing, pack_ffd
 from repro.core.resource_model import RamPrimitive
+from repro.models.attention import (
+    SCRATCH_BLOCK,  # never allocated; idle lanes' tables point at it
+    pool_tile,
+    tiles_to_tokens,
+    tokens_to_tiles,
+)
 from repro.models.config import PAGED_FAMILIES, ModelConfig
 
-SCRATCH_BLOCK = 0  # block 0 is never allocated; idle slots write/read it
 
-# in-place row insertion into a donated pool buffer (one trace per
-# (pool shape, row count); the .at[].set outside jit would copy the pool)
-_row_scatter = jax.jit(
-    lambda pool, rows, vals: pool.at[:, rows].set(vals), donate_argnums=(0,)
-)
+@functools.partial(jax.jit, donate_argnums=(0,))
+def write_blocks(pool, ids, rows):
+    """Write (L, n, n_kv, hd) rows, n a whole number of blocks, into
+    blocks ``ids`` in place on the donated pool (one trace per row count;
+    the .at[].set outside jit would copy the pool)."""
+    t = rows.shape[1] // ids.shape[0]
+    return pool.at[:, ids].set(
+        tokens_to_tiles(rows.astype(pool.dtype), t, pool.shape[-2:])
+    )
 
-# copy-on-write block duplication: gather the source block's rows and
-# scatter them into the destination block, in place on the donated pool
+
+# copy-on-write block duplication: block ``src``'s tile copied into block
+# ``dst`` in every layer, in place on the donated pool
 _block_copy = jax.jit(
     lambda pool, dst, src: pool.at[:, dst].set(pool[:, src]),
     donate_argnums=(0,),
@@ -142,11 +153,13 @@ class PoolStats:
 class KVPool:
     """One contiguous physical KV cache with refcounted block sharing.
 
-    Device side: ``k``/``v`` are (L, n_blocks * block_tokens, n_kv, hd)
-    row-addressed arrays (the block is an allocator concept only). Host
-    side: a free-block inventory, per-request block tables that may
-    *alias* each other on shared prefixes, a per-block refcount, and the
-    set of blocks pinned by the attached prefix cache.
+    Device side: ``k``/``v`` are (L, n_blocks, rows, width) arrays, one
+    block-contiguous tile per block (``attention.pool_tile``: the block's
+    tokens x heads x head dim, head-major, in lane-dense rows), so a
+    step reads a lane's blocks whole and in place. Host side: a
+    free-block inventory, per-request block tables that may *alias* each
+    other on shared prefixes, a per-block refcount, and the set of blocks
+    pinned by the attached prefix cache.
 
     Admission reserves a *commitment* (the request's full block need from
     ``blocks_for``) but hands out blocks lazily as tokens arrive, so
@@ -181,10 +194,11 @@ class KVPool:
         self.block_tokens = block_tokens
         self.ram = kv_block_ram(block_tokens)
         dt = jnp.dtype(dtype or cfg.dtype)
-        rows = n_blocks * block_tokens
         # hybrid holds one growing KV cache per *shared* attention block
         # (n_super of them), not per layer
-        shape = (cfg.n_kv_cache_layers, rows, cfg.n_kv, cfg.hd)
+        shape = (cfg.n_kv_cache_layers, n_blocks) + pool_tile(
+            cfg.n_kv, block_tokens, cfg.hd
+        )
         self.k = jnp.zeros(shape, dt)
         self.v = jnp.zeros(shape, dt)
         # block 0 reserved as scratch for idle decode lanes
@@ -300,10 +314,6 @@ class KVPool:
 
     def ref_count(self, block: int) -> int:
         return self._refs.get(block, 0)
-
-    def max_rows(self, max_tokens: int) -> int:
-        """Fixed gather width for a serve step admitting <= max_tokens."""
-        return self.blocks_for(max_tokens) * self.block_tokens
 
     # ---------------- lifecycle ----------------
 
@@ -485,10 +495,9 @@ class KVPool:
             if tail_block == SCRATCH_BLOCK or tail_block not in self._refs:
                 raise ValueError(f"cannot adopt unallocated block {tail_block}")
             new = self._pop_free()
-            src = np.arange(tail_block * t, (tail_block + 1) * t)
-            dst = np.arange(new * t, (new + 1) * t)
-            self.k = _block_copy(self.k, jnp.asarray(dst), jnp.asarray(src))
-            self.v = _block_copy(self.v, jnp.asarray(dst), jnp.asarray(src))
+            dst, src = jnp.int32(new), jnp.int32(tail_block)
+            self.k = _block_copy(self.k, dst, src)
+            self.v = _block_copy(self.v, dst, src)
             self._add_user(new)
             held.append(new)
             self.cow_copies += 1
@@ -571,19 +580,13 @@ class KVPool:
 
     # ---------------- device-side addressing ----------------
 
-    def rows_of(self, rid: int, pad_to: int | None = None) -> np.ndarray:
-        """Physical row indices of the request's tokens, scratch-padded."""
-        t = self.block_tokens
-        rows = np.concatenate(
-            [np.arange(b * t, (b + 1) * t) for b in self._held[rid]]
-        ) if self._held[rid] else np.zeros((0,), np.int64)
-        if pad_to is not None:
-            pad = np.full((pad_to - len(rows),), SCRATCH_BLOCK * t, np.int64)
-            rows = np.concatenate([rows, pad])
-        return rows.astype(np.int32)
-
-    def scratch_rows(self, pad_to: int) -> np.ndarray:
-        return np.full((pad_to,), SCRATCH_BLOCK * self.block_tokens, np.int32)
+    def table_of(self, rid: int, n_entries: int) -> np.ndarray:
+        """The request's block table: its blocks in position order, padded
+        to ``n_entries`` with the scratch block."""
+        table = np.full((n_entries,), SCRATCH_BLOCK, np.int32)
+        held = self._held[rid][:n_entries]
+        table[: len(held)] = held
+        return table
 
     def write_prefill(
         self,
@@ -592,43 +595,48 @@ class KVPool:
         vs: jnp.ndarray,
         n_tokens: int | None = None,
     ) -> None:
-        """Scatter a prefilled (L, P, n_kv, hd) KV prefix into the pool.
+        """Write a prefilled (L, P, n_kv, hd) KV prefix into the pool.
 
         Cold-path only: the request's blocks must be private (a warm
         prefix-cache admission writes its suffix through the chunked
         prefill steps instead, which never touch adopted shared rows).
         ``ks``/``vs`` may be right-padded past ``n_tokens`` (the prefill
-        bucket); padded rows land in the scratch block so the jitted
-        scatter traces once per bucket size, and the donated pool buffer
-        updates in place instead of copying the whole pool per admission.
+        bucket); the pad fills the tail of the last block, where nothing
+        valid lies yet, and whole blocks past the request's land in the
+        scratch block, so the jitted write traces once per bucket size,
+        and the donated pool buffer updates in place instead of copying
+        the whole pool per admission.
         """
         p = n_tokens if n_tokens is not None else ks.shape[1]
         self.note_tokens(rid, p)
-        rows = self.rows_of(rid)[:p]
-        if ks.shape[1] > p:
-            pad = np.full(
-                (ks.shape[1] - p,), SCRATCH_BLOCK * self.block_tokens, np.int32
-            )
-            rows = np.concatenate([rows, pad])
-        rows = jnp.asarray(rows)
-        self.k = _row_scatter(self.k, rows, ks.astype(self.k.dtype))
-        self.v = _row_scatter(self.v, rows, vs.astype(self.v.dtype))
+        t = self.block_tokens
+        pad = -ks.shape[1] % t
+        if pad:
+            widths = ((0, 0), (0, pad), (0, 0), (0, 0))
+            ks, vs = jnp.pad(ks, widths), jnp.pad(vs, widths)
+        ids = jnp.asarray(self.table_of(rid, ks.shape[1] // t))
+        self.k = write_blocks(self.k, ids, ks)
+        self.v = write_blocks(self.v, ids, vs)
 
     def export_blocks(
         self, rid: int, n_tokens: int | None = None
     ) -> tuple[tuple[int, ...], np.ndarray, np.ndarray]:
         """Snapshot a request's KV for handoff, serialized in block-id
         order: returns (block ids, K rows, V rows) with the row payloads
-        shaped (L, n_tokens, n_kv, hd) — rows_of() gathers rows in the
-        order the blocks were allocated, so the ids fully describe the
-        payload layout and a block-granular transport could ship the
-        physical blocks as-is. Shared (prefix-cache) blocks export by
-        value like any other: the importing pool allocates its own
-        blocks, so refcounts stay engine-local and intact."""
+        shaped (L, n_tokens, n_kv, hd) — the blocks are read in table
+        order, so the ids fully describe the payload layout and a
+        block-granular transport could ship the physical blocks as-is.
+        Shared (prefix-cache) blocks export by value like any other: the
+        importing pool allocates its own blocks, so refcounts stay
+        engine-local and intact."""
         ids = tuple(self._held[rid])
         n = n_tokens if n_tokens is not None else self._tokens[rid]
-        rows = jnp.asarray(self.rows_of(rid)[:n])
-        return ids, np.asarray(self.k[:, rows]), np.asarray(self.v[:, rows])
+        idx = jnp.asarray(ids, jnp.int32)
+        cfg = self.cfg
+        return ids, *(
+            np.asarray(tiles_to_tokens(pool[:, idx], cfg.n_kv, cfg.hd)[:, :n])
+            for pool in (self.k, self.v)
+        )
 
     # ---------------- accounting / reporting ----------------
 

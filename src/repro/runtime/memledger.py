@@ -76,12 +76,12 @@ GAUGES = (
 def kv_block_bytes(pool) -> int:
     """Bytes of KV cache backing one pool block (both K and V planes).
 
-    The pool arrays are row-addressed (L, n_blocks * block_tokens, n_kv,
-    hd); a block is ``block_tokens`` rows of both planes.
+    The pool arrays are (L, n_blocks, rows, width), one tile per block;
+    a block is one tile of each layer in both planes.
     """
     k = pool.k
-    layers, _, n_kv, hd = k.shape
-    return int(k.dtype.itemsize) * layers * pool.block_tokens * n_kv * hd * 2
+    layers, _, rows, width = k.shape
+    return int(k.dtype.itemsize) * layers * rows * width * 2
 
 
 def _snapshot(pool) -> dict:

@@ -38,7 +38,7 @@ def make_budgeted_paged_serve_step(
     """Pool-indexed serve step running against the plan's budgeted set.
 
     Same signature as ``steps.make_paged_serve_step``: (params, token,
-    pool_k, pool_v, row_table, lengths) -> (logits, pool_k, pool_v)
+    pool_k, pool_v, block_table, lengths) -> (logits, pool_k, pool_v)
     (+ a per-layer expert-load tally for moe). The mask granularity
     follows the family: (L,) layers for dense/vlm, (L, E) experts for
     moe — cold experts stream their w1/w3/w2 through the DMA ring while

@@ -40,6 +40,11 @@ from repro.models.config import (
     PREFIX_CACHE_FAMILIES,
     ModelConfig,
 )
+from repro.models.attention import (
+    SCRATCH_BLOCK,
+    decode_window,
+    live_block_span,
+)
 from repro.models.lm import (
     STEP_SCOPES,
     SamplingParams,
@@ -88,7 +93,7 @@ def _jitted_verify(cfg: ModelConfig):
 @functools.lru_cache(maxsize=None)
 def _jitted_hybrid_suffix(cfg: ModelConfig):
     return jax.jit(
-        make_hybrid_suffix_prefill_step(cfg), donate_argnums=(2, 3, 8)
+        make_hybrid_suffix_prefill_step(cfg), donate_argnums=(2, 3, 7)
     )
 
 
@@ -185,6 +190,10 @@ class SchedulerStats:
     util_samples_any: list[float] = dataclasses.field(default_factory=list)
     shared_blocks_peak: int = 0
     decode_time: float = 0.0
+    # K/V blocks per layer the paged decode attention visits, over all
+    # decode steps: each lane's live blocks (``live_block_span``) where
+    # the kernel runs, every table entry where the reference does
+    kv_blocks_read: int = 0
 
     @property
     def mean_ttft(self) -> float:
@@ -245,7 +254,12 @@ class Scheduler:
         self.pool = pool
         self.slots = slots
         self.max_len = max_len
-        self.s_max = pool.max_rows(max_len)
+        self.n_table = pool.blocks_for(max_len)  # block-table width
+        # whether the decode step's kernel reads only each lane's live
+        # blocks; the reference formulation reads every lane's whole table
+        from repro.kernels import ops
+
+        self._reads_live = ops.paged_decode_runs_kernel(cfg.hd, pool.k)
         usable_tokens = pool.usable_blocks * pool.block_tokens
         self.token_budget = min(token_budget or usable_tokens, usable_tokens)
         # serving Eq. 2: R_F rounds of decode per admission round
@@ -321,11 +335,13 @@ class Scheduler:
         self.active: list[int | None] = [None] * slots
         self._token = np.zeros((slots, 1), np.int32)
         self._lengths = np.zeros((slots,), np.int32)
-        # per-lane physical row tables, updated on admission / block
-        # growth / completion only (not rebuilt every decode step); the
-        # device copy is re-uploaded only when an event dirties the table
-        self._row_table = np.tile(pool.scratch_rows(self.s_max), (slots, 1))
-        self._row_table_dev = jnp.asarray(self._row_table)
+        # per-lane block tables, updated on admission / block growth /
+        # completion only (not rebuilt every decode step); the device copy
+        # is re-uploaded only when an event dirties the table
+        self._block_table = np.full(
+            (slots, self.n_table), SCRATCH_BLOCK, np.int32
+        )
+        self._block_table_dev = jnp.asarray(self._block_table)
         self._table_dirty = False
         self._next_rid = 0
         self.stats = SchedulerStats()
@@ -441,12 +457,13 @@ class Scheduler:
                 "decode", scope_table(compiled.as_text(), STEP_SCOPES)
             )
 
-    def _phase(self, name: str):
+    def _phase(self, name: str, **attrs):
         """Round phase ``name`` (listed in ``runtime.spans``): the
-        profiler annotation, and a record when the recorder is tracked."""
+        profiler annotation, and a record carrying ``attrs`` when the
+        recorder is tracked."""
         if self._spans is None:
             return phase_annotation(name)
-        return self._spans.phase(name, round=self.stats.rounds)
+        return self._spans.phase(name, round=self.stats.rounds, **attrs)
 
     # ---------------- submission ----------------
 
@@ -531,7 +548,7 @@ class Scheduler:
             self.active[slot] = None
             self._token[slot, 0] = 0
             self._lengths[slot] = 0
-            self._row_table[slot] = self.pool.scratch_rows(self.s_max)
+            self._block_table[slot] = SCRATCH_BLOCK
             self._table_dirty = True
             req.output.clear()
             req._enter(RequestState.QUEUED)
@@ -637,7 +654,7 @@ class Scheduler:
         p = len(req.prompt)
         self._token[slot, 0] = first
         self._lengths[slot] = p
-        self._row_table[slot] = self.pool.rows_of(req.rid, pad_to=self.s_max)
+        self._block_table[slot] = self.pool.table_of(req.rid, self.n_table)
         self._table_dirty = True
         if self.speculative is not None:
             self._start_drafter(slot, req)
@@ -739,8 +756,8 @@ class Scheduler:
         self.active[slot] = payload.rid
         self._token[slot, 0] = payload.first_token
         self._lengths[slot] = payload.n_tokens
-        self._row_table[slot] = self.pool.rows_of(
-            payload.rid, pad_to=self.s_max
+        self._block_table[slot] = self.pool.table_of(
+            payload.rid, self.n_table
         )
         self._table_dirty = True
         if self.spans is not None:
@@ -887,8 +904,9 @@ class Scheduler:
     def _prefill_one_chunk(self, slot: int) -> None:
         """Run one ``prefill_chunk``-sized piece of a long prompt.
 
-        Attention families pad the chunk to the fixed chunk width with
-        scratch rows (one trace total). Hybrid chunks run *unpadded* —
+        Attention families pad the chunk to the fixed chunk width (one
+        trace total); the padding's rows land past the prompt, where
+        nothing valid lies yet. Hybrid chunks run *unpadded* —
         the SSD state integrates every fed position, so a padded tail
         would pollute the carried state — and thread ``_chunk_lane``
         through ``lm.prefill_suffix_paged_hybrid``: each chunk resumes
@@ -904,8 +922,7 @@ class Scheduler:
             n = min(c, p - c0)
             t0 = self.spans.now() if self.spans is not None else 0.0
             self.pool.note_tokens(rid, c0 + n)
-            rows = self.pool.rows_of(rid)[c0 : c0 + n]
-            row_table = self.pool.rows_of(rid, pad_to=self.s_max)[None]
+            table = jnp.asarray(self.pool.table_of(rid, self.n_table)[None])
             if self.cfg.family == "hybrid":
                 logits, self.pool.k, self.pool.v, self._chunk_lane[rid] = (
                     self._hybrid_suffix(
@@ -913,17 +930,13 @@ class Scheduler:
                         jnp.asarray(req.prompt[c0 : c0 + n][None]),
                         self.pool.k,
                         self.pool.v,
-                        jnp.asarray(row_table),
-                        jnp.asarray(rows[None]),
+                        table,
                         jnp.asarray(c0, jnp.int32),
                         jnp.asarray(n - 1, jnp.int32),
                         self._chunk_lane[rid],
                     )
                 )
             else:
-                scratch = int(self.pool.scratch_rows(1)[0])
-                write_rows = np.full((1, c), scratch, np.int32)
-                write_rows[0, :n] = rows
                 tokens = np.zeros((1, c), np.int32)
                 tokens[0, :n] = req.prompt[c0 : c0 + n]
                 out = self._chunk_prefill(
@@ -931,8 +944,7 @@ class Scheduler:
                     jnp.asarray(tokens),
                     self.pool.k,
                     self.pool.v,
-                    jnp.asarray(row_table),
-                    jnp.asarray(write_rows),
+                    table,
                     jnp.asarray(c0, jnp.int32),
                     jnp.asarray(n - 1, jnp.int32),
                 )
@@ -997,7 +1009,7 @@ class Scheduler:
         self.active[slot] = None
         self._token[slot, 0] = 0
         self._lengths[slot] = 0
-        self._row_table[slot] = self.pool.scratch_rows(self.s_max)
+        self._block_table[slot] = SCRATCH_BLOCK
         self._table_dirty = True
         self.stats.completed += 1
         self.stats.generated_tokens += len(req.output)
@@ -1023,15 +1035,40 @@ class Scheduler:
             jnp.asarray(self._token),
             self.pool.k,
             self.pool.v,
-            self._row_table_dev,
+            self._block_table_dev,
             jnp.asarray(self._lengths),
         )
         if self.cfg.family == "hybrid":
             args += (self._lane_state,)
         return args
 
+    def _sync_table(self, i: int, rid: int, before: int) -> None:
+        """Refresh lane ``i``'s block table if its request's block count
+        moved from ``before``."""
+        if self.pool.blocks_held(rid) != before:
+            self._block_table[i] = self.pool.table_of(rid, self.n_table)
+            self._table_dirty = True
+
+    def _upload_table(self) -> None:
+        if self._table_dirty:
+            self._block_table_dev = jnp.asarray(self._block_table)
+            self._table_dirty = False
+
     def _decode_step(self) -> None:
-        with self._phase("decode_dispatch"):
+        # the kernel reads every lane's live blocks, the scratch block of
+        # an idle one too; the reference reads every table entry
+        table = self.slots * self.n_table
+        read = table
+        if self._reads_live:
+            first, end = live_block_span(
+                self._lengths + 1, self.pool.block_tokens,
+                decode_window(self.cfg),
+            )
+            read = int((end - first).sum())
+        self.stats.kv_blocks_read += read
+        with self._phase(
+            "decode_dispatch", kv_blocks=read, kv_table_blocks=table
+        ):
             t0_step = self.spans.now() if self.spans is not None else 0.0
             for i, rid in enumerate(self.active):
                 if not self._decoding(rid):
@@ -1039,14 +1076,8 @@ class Scheduler:
                 # room for the incoming token's KV row
                 before = self.pool.blocks_held(rid)
                 self.pool.note_tokens(rid, int(self._lengths[i]) + 1)
-                if self.pool.blocks_held(rid) != before:
-                    self._row_table[i] = self.pool.rows_of(
-                        rid, pad_to=self.s_max
-                    )
-                    self._table_dirty = True
-            if self._table_dirty:
-                self._row_table_dev = jnp.asarray(self._row_table)
-                self._table_dirty = False
+                self._sync_table(i, rid, before)
+            self._upload_table()
             out = self._decode(*self._decode_args())
         if self.cfg.family == "hybrid":
             logits, self.pool.k, self.pool.v, self._lane_state = out
@@ -1158,33 +1189,22 @@ class Scheduler:
                 self.pool.begin_draft(
                     rid, int(self._lengths[i]) + k_eff[rid]
                 )
-                if self.pool.blocks_held(rid) != before:
-                    self._row_table[i] = self.pool.rows_of(
-                        rid, pad_to=self.s_max
-                    )
-                    self._table_dirty = True
-            if self._table_dirty:
-                self._row_table_dev = jnp.asarray(self._row_table)
-                self._table_dirty = False
-            scratch = int(self.pool.scratch_rows(1)[0])
+                self._sync_table(i, rid, before)
+            self._upload_table()
             tokens = np.zeros((self.slots, kmax), np.int32)
-            write_rows = np.full((self.slots, kmax), scratch, np.int32)
             starts = np.zeros((self.slots,), np.int32)
             for i, rid in lanes:
                 ke = k_eff[rid]
-                n = int(self._lengths[i])
                 tokens[i, 0] = self._token[i, 0]
                 if ke > 1:
                     tokens[i, 1:ke] = props[rid][: ke - 1]
-                write_rows[i, :ke] = self.pool.rows_of(rid)[n : n + ke]
-                starts[i] = n
+                starts[i] = self._lengths[i]
             out = self._verify(
                 self.params,
                 jnp.asarray(tokens),
                 self.pool.k,
                 self.pool.v,
-                self._row_table_dev,
-                jnp.asarray(write_rows),
+                self._block_table_dev,
                 jnp.asarray(starts),
             )
         if self.cfg.family == "moe":
@@ -1228,11 +1248,7 @@ class Scheduler:
                 self._lengths[i] = n0 + accepted
                 before = self.pool.blocks_held(rid)
                 self.pool.end_draft(rid, n0 + accepted)
-                if self.pool.blocks_held(rid) != before:
-                    self._row_table[i] = self.pool.rows_of(
-                        rid, pad_to=self.s_max
-                    )
-                    self._table_dirty = True
+                self._sync_table(i, rid, before)
                 self.speculative.accept(i, n0 + accepted)
                 if len(req.output) >= req.max_new_tokens:
                     done_slots.append(i)

@@ -45,7 +45,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.models import lm
+from repro.models.attention import pool_tile
 from repro.models.config import ModelConfig
+from repro.runtime.kv_pool import write_blocks
 from repro.runtime.steps import make_paged_serve_step, make_pool_prefill_step
 
 # families verify_chunk_paged serves (hybrid's SSM lanes cannot roll back)
@@ -66,11 +68,8 @@ def _jitted_draft_prefill(cfg: ModelConfig):
     return jax.jit(make_pool_prefill_step(cfg))
 
 
-# in-place row insertion into the drafter's donated KV buffers (same
-# pattern as kv_pool._row_scatter; one trace per pool/row-count shape)
-_draft_scatter = jax.jit(
-    lambda pool, rows, vals: pool.at[:, rows].set(vals), donate_argnums=(0,)
-)
+# tokens per block of a model drafter's private pool
+DRAFT_BLOCK_TOKENS = 16
 
 
 @dataclasses.dataclass(frozen=True)
@@ -321,12 +320,12 @@ class ModelDrafter:
     """A packed-twin (or foreign-arch) model drafter with private KV.
 
     The drafter runs the standard paged decode step over its own
-    fixed-geometry pool: lane ``i`` owns the contiguous rows
-    ``[1 + i*S, 1 + (i+1)*S)`` (row 0 is scratch for prefill padding),
-    so its row table is static and rollback is just clamping the lane
-    length — the rollout feeds exactly the tokens the verifier feeds, so
-    rows under the accepted prefix are already correct and rows past it
-    are overwritten by the next chain.
+    fixed-geometry pool: lane ``i`` owns the contiguous blocks
+    ``[1 + i*nb, 1 + (i+1)*nb)`` (block 0 is scratch), so its block table
+    is static and rollback is just clamping the lane length — the rollout
+    feeds exactly the tokens the verifier feeds, so rows under the
+    accepted prefix are already correct and rows past it are overwritten
+    by the next chain.
     """
 
     is_model = True
@@ -338,13 +337,17 @@ class ModelDrafter:
         self.params = params
         self.slots = slots
         self.s = max_len
-        rows = 1 + slots * max_len
-        shape = (cfg.n_kv_cache_layers, rows, cfg.n_kv, cfg.hd)
+        t = DRAFT_BLOCK_TOKENS
+        self._nb = -(-max_len // t)
+        shape = (cfg.n_kv_cache_layers, 1 + slots * self._nb) + pool_tile(
+            cfg.n_kv, t, cfg.hd
+        )
         dt = jnp.dtype(cfg.dtype)
         self.k = jnp.zeros(shape, dt)
         self.v = jnp.zeros(shape, dt)
-        table = 1 + np.arange(slots)[:, None] * max_len + np.arange(max_len)
-        self._row_table_dev = jnp.asarray(table.astype(np.int32))
+        table = 1 + np.arange(slots * self._nb).reshape(slots, self._nb)
+        self._block_table = table.astype(np.int32)
+        self._block_table_dev = jnp.asarray(self._block_table)
         self.lengths = np.zeros((slots,), np.int32)
         self._decode = _jitted_draft_decode(cfg)
         self._prefill = _jitted_draft_prefill(cfg)
@@ -361,14 +364,12 @@ class ModelDrafter:
         padded = np.zeros((1, self.s), np.int32)
         padded[0, :p] = prompt
         _, ks, vs = self._prefill(self.params, jnp.asarray(padded), p - 1)
-        rows = np.zeros((self.s,), np.int32)  # padded tail -> scratch row 0
-        rows[:p] = 1 + slot * self.s + np.arange(p)
-        self.k = _draft_scatter(
-            self.k, jnp.asarray(rows), ks[:, 0].astype(self.k.dtype)
-        )
-        self.v = _draft_scatter(
-            self.v, jnp.asarray(rows), vs[:, 0].astype(self.v.dtype)
-        )
+        # the padded tail lands past the prompt in the lane's own blocks
+        pad = ((0, 0), (0, self._nb * DRAFT_BLOCK_TOKENS - self.s), (0, 0),
+               (0, 0))
+        ids = jnp.asarray(self._block_table[slot])
+        self.k = write_blocks(self.k, ids, jnp.pad(ks[:, 0], pad))
+        self.v = write_blocks(self.v, ids, jnp.pad(vs[:, 0], pad))
         self.lengths[slot] = p
         return p, 1
 
@@ -406,7 +407,7 @@ class ModelDrafter:
                 jnp.asarray(token),
                 self.k,
                 self.v,
-                self._row_table_dev,
+                self._block_table_dev,
                 jnp.asarray(lengths),
             )
             steps += 1

@@ -104,10 +104,10 @@ def make_serve_step(cfg: ModelConfig) -> Callable:
 def make_paged_serve_step(cfg: ModelConfig) -> Callable:
     """Pool-indexed serve step for the continuous-batching scheduler.
 
-    (params, token (B,1), pool_k, pool_v, row_table (B,S_max), lengths (B,))
-    -> (logits (B,1,V), new pool_k, new pool_v). Each decode lane gathers
-    its KV rows from the shared physical pool through ``row_table`` and
-    scatters the new token's row back — the gather/scatter analog of the
+    (params, token (B,1), pool_k, pool_v, block_table (B, nb), lengths
+    (B,)) -> (logits (B,1,V), new pool_k, new pool_v). Each decode lane
+    writes the new token's row into its block of the shared physical pool
+    and reads its live blocks through ``block_table`` — the analog of the
     paper's round-robin port schedule over a packed BRAM. The moe family
     appends a per-layer expert-load tally (L, E) to the return. Jit with
     ``donate_argnums=(2, 3)`` so the pool updates in place.
@@ -115,21 +115,21 @@ def make_paged_serve_step(cfg: ModelConfig) -> Callable:
 
     if cfg.family == "hybrid":
         # extended signature: the per-lane SSM state travels with the step
-        # (params, token, pool_k, pool_v, row_table, lengths, lane_state)
+        # (params, token, pool_k, pool_v, block_table, lengths, lane_state)
         # -> (logits, pool_k, pool_v, lane_state)
         def hybrid_step(
-            params, token, pool_k, pool_v, row_table, lengths, lane_state
+            params, token, pool_k, pool_v, block_table, lengths, lane_state
         ):
             return lm.decode_step_paged_hybrid(
-                params, cfg, token, pool_k, pool_v, row_table, lengths,
+                params, cfg, token, pool_k, pool_v, block_table, lengths,
                 lane_state,
             )
 
         return hybrid_step
 
-    def step(params, token, pool_k, pool_v, row_table, lengths):
+    def step(params, token, pool_k, pool_v, block_table, lengths):
         return lm.decode_step_paged(
-            params, cfg, token, pool_k, pool_v, row_table, lengths
+            params, cfg, token, pool_k, pool_v, block_table, lengths
         )
 
     return step
@@ -161,18 +161,17 @@ def make_pool_prefill_step(cfg: ModelConfig) -> Callable:
 def make_chunk_prefill_step(cfg: ModelConfig) -> Callable:
     """One prompt-chunk prefill against the pool (chunked admission).
 
-    (params, tokens (B, C), pool_k, pool_v, row_table (B, S_max),
-    write_rows (B, C), start (), last_idx ()) -> (logits at last_idx
-    (B, 1, V), new pool_k, new pool_v). ``start`` is traced, so one trace
-    serves every chunk offset of every request. Jit with
-    ``donate_argnums=(2, 3)`` so the pool updates in place.
+    (params, tokens (B, C), pool_k, pool_v, block_table (B, nb), start
+    (), last_idx ()) -> (logits at last_idx (B, 1, V), new pool_k, new
+    pool_v). ``start`` is traced, so one trace serves every chunk offset
+    of every request. Jit with ``donate_argnums=(2, 3)`` so the pool
+    updates in place.
     """
 
-    def step(params, tokens, pool_k, pool_v, row_table, write_rows, start,
-             last_idx):
+    def step(params, tokens, pool_k, pool_v, block_table, start, last_idx):
         return lm.prefill_chunk_paged(
-            params, cfg, tokens, pool_k, pool_v, row_table, write_rows,
-            start, last_idx,
+            params, cfg, tokens, pool_k, pool_v, block_table, start,
+            last_idx,
         )
 
     return step
@@ -181,18 +180,17 @@ def make_chunk_prefill_step(cfg: ModelConfig) -> Callable:
 def make_verify_step(cfg: ModelConfig) -> Callable:
     """Batched draft-chain verification against the pool (speculative).
 
-    (params, tokens (B, C), pool_k, pool_v, row_table (B, S_max),
-    write_rows (B, C), starts (B,)) -> (full logits (B, C, V), new
-    pool_k, new pool_v). One call scores every lane's pending token plus
-    its drafter proposals at per-lane offsets; ``runtime.speculative``
-    turns the returned distributions into a longest-accepted prefix. Jit
-    with ``donate_argnums=(2, 3)`` so the pool updates in place.
+    (params, tokens (B, C), pool_k, pool_v, block_table (B, nb), starts
+    (B,)) -> (full logits (B, C, V), new pool_k, new pool_v). One call
+    scores every lane's pending token plus its drafter proposals at
+    per-lane offsets; ``runtime.speculative`` turns the returned
+    distributions into a longest-accepted prefix. Jit with
+    ``donate_argnums=(2, 3)`` so the pool updates in place.
     """
 
-    def step(params, tokens, pool_k, pool_v, row_table, write_rows, starts):
+    def step(params, tokens, pool_k, pool_v, block_table, starts):
         return lm.verify_chunk_paged(
-            params, cfg, tokens, pool_k, pool_v, row_table, write_rows,
-            starts,
+            params, cfg, tokens, pool_k, pool_v, block_table, starts
         )
 
     return step
@@ -201,20 +199,19 @@ def make_verify_step(cfg: ModelConfig) -> Callable:
 def make_hybrid_suffix_prefill_step(cfg: ModelConfig) -> Callable:
     """Hybrid prompt-suffix prefill resuming from carried SSM state.
 
-    (params, tokens (B, C) unpadded suffix, pool_k, pool_v, row_table
-    (B, S_max), write_rows (B, C), start (), last_idx (), lane_state) ->
-    (logits at last_idx (B, 1, V), new pool_k, new pool_v, new
-    lane_state). The prefix-cache warm path for zamba2: the matched
-    prefix's shared-attention KV is gathered from the pool and the SSD
-    recurrence seeds from the anchor's lane-state snapshot. Jit with
-    ``donate_argnums=(2, 3, 8)``.
+    (params, tokens (B, C) unpadded suffix, pool_k, pool_v, block_table
+    (B, nb), start (), last_idx (), lane_state) -> (logits at last_idx
+    (B, 1, V), new pool_k, new pool_v, new lane_state). The prefix-cache
+    warm path for zamba2: the matched prefix's shared-attention KV is
+    gathered from the pool and the SSD recurrence seeds from the anchor's
+    lane-state snapshot. Jit with ``donate_argnums=(2, 3, 7)``.
     """
 
-    def step(params, tokens, pool_k, pool_v, row_table, write_rows, start,
-             last_idx, lane_state):
+    def step(params, tokens, pool_k, pool_v, block_table, start, last_idx,
+             lane_state):
         return lm.prefill_suffix_paged_hybrid(
-            params, cfg, tokens, pool_k, pool_v, row_table, write_rows,
-            start, last_idx, lane_state,
+            params, cfg, tokens, pool_k, pool_v, block_table, start,
+            last_idx, lane_state,
         )
 
     return step
@@ -233,9 +230,9 @@ def make_budgeted_paged_serve_step(
     """
     mask = jnp.asarray(stream_mask, bool)
 
-    def step(params, token, pool_k, pool_v, row_table, lengths):
+    def step(params, token, pool_k, pool_v, block_table, lengths):
         return lm.decode_step_paged(
-            params, cfg, token, pool_k, pool_v, row_table, lengths,
+            params, cfg, token, pool_k, pool_v, block_table, lengths,
             stream_mask=mask, stream_depth=stream_depth,
         )
 

@@ -241,6 +241,7 @@ DELTA_KEYS = (
     "accepted_tokens",
     "draft_tokens",
     "verify_steps",
+    "kv_blocks_read",
 )
 
 # SchedulerStats fields that are deliberately NOT replayed as deltas:

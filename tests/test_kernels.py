@@ -1,6 +1,8 @@
 """Pallas kernel validation: shape/dtype sweeps vs the pure-jnp oracles
 (interpret mode executes the kernel body on CPU)."""
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -207,3 +209,128 @@ def test_flash_kernel_dtypes(dtype):
         np.asarray(got, np.float32), np.asarray(want, np.float32),
         rtol=tol, atol=tol,
     )
+
+
+# --------------------------------------------------------------------------
+# Paged decode attention over the block pool (kernels/paged_attention.py)
+# --------------------------------------------------------------------------
+
+
+def _paged_case(case, dtype):
+    """(q, k_new, v_new, pool_k, pool_v, block_table, lengths, n_kv,
+    window, live lanes) of one case, at rehearsal widths (2 kv heads of
+    32, 2 query heads each); ``lengths`` are the tokens each lane holds
+    before the step's new one."""
+    from repro.configs import get_smoke_config
+    from repro.models.attention import pool_tile
+    from repro.runtime.kv_pool import KVPool
+
+    n_kv, g, hd, t = 2, 2, 32, 16
+    window, nb = 0, 128
+    rng = np.random.default_rng(7)
+    pools = tables = None
+    if case == "ragged":  # nothing, one, both sides of a block edge, full,
+        # and a last lane whose new row falls past its table (nb * t)
+        lengths = [0, 1, 15, 16, 2047, 2048]
+    elif case == "past_end":
+        # drafter lanes rolled past max_len: their rows go to the scratch
+        # block, never into the next lane's first block
+        lengths = [2048, 2050, 30, 2048]
+    elif case == "parked":  # three lanes parked on the scratch block
+        lengths = [0, 0, 40, 0]
+        tables = np.zeros((4, nb), np.int32)
+        tables[2, :3] = [5, 9, 2]
+    elif case == "shared":
+        # request 1 adopts request 0's two full blocks and takes a private
+        # copy of its partly matched third (copy-on-write)
+        cfg = dataclasses.replace(get_smoke_config("smollm_360m"), n_kv=n_kv,
+                                  head_dim=hd, dtype=dtype)
+        pool = KVPool(cfg, n_blocks=12, block_tokens=t)
+        pool.k = jnp.asarray(rng.normal(size=pool.k.shape), dtype)
+        pool.v = jnp.asarray(rng.normal(size=pool.v.shape), dtype)
+        pool.admit(0, 64)
+        pool.note_tokens(0, 46)
+        pool.admit(1, 64)
+        held = pool.blocks_of(0)
+        pool.adopt_prefix(1, held[:2], held[2], 40)
+        pool.note_tokens(1, 51)
+        assert pool.blocks_of(1)[:2] == held[:2] and pool.cow_copies == 1
+        tables = np.stack([pool.table_of(r, nb) for r in (0, 1)])
+        lengths = [45, 50]
+        pools = pool.k, pool.v
+    else:  # a sliding window of 20 positions
+        window = 20
+        lengths = [4, 19, 40, 699]
+    n_lanes = len(lengths)
+    if tables is None:  # scattered, non-contiguous physical blocks
+        perm = 1 + rng.permutation(n_lanes * nb)
+        tables = perm.reshape(n_lanes, nb).astype(np.int32)
+    if pools is None:
+        shape = (2, 1 + n_lanes * nb) + pool_tile(n_kv, t, hd)
+        pools = tuple(jnp.asarray(rng.normal(size=shape), dtype)
+                      for _ in range(2))
+    q, k_new, v_new = (
+        jnp.asarray(rng.normal(size=(n_lanes, 1, n_kv * g, hd)), dtype)
+        for _ in range(3)
+    )
+    live = [i for i in range(n_lanes) if tables[i].any()]
+    return (q, k_new[:, 0, :n_kv], v_new[:, 0, :n_kv], *pools,
+            jnp.asarray(tables), jnp.asarray(lengths, jnp.int32), n_kv,
+            window, live)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize(
+    "case", ["ragged", "past_end", "parked", "shared", "window"]
+)
+def test_paged_decode_kernel_matches_reference(case, dtype):
+    """The block-table kernel (interpret mode) writes each lane's new row
+    and gives the reference's attention: ragged lane lengths up to the
+    full 2048 positions and past them, lanes parked on the scratch block,
+    blocks two lanes share after prefix adoption and copy-on-write, and a
+    sliding window."""
+    from repro.kernels.paged_attention import paged_decode
+    from repro.models.attention import SCRATCH_BLOCK
+
+    (q, k_new, v_new, pk, pv, table, lengths, n_kv, window,
+     live) = _paged_case(case, dtype)
+    layer = jnp.int32(pk.shape[0] - 1)
+    args = (q, k_new, v_new, pk, pv, layer, table, lengths)
+    got = paged_decode(*args, n_kv=n_kv, window=window, interpret=True)
+    want = ref.paged_decode_ref(*args, n_kv=n_kv, window=window)
+    assert got[0].shape == want[0].shape and got[0].dtype == dtype
+    tol = 1e-5 if dtype == jnp.float32 else 2e-2
+    f32 = lambda x: np.asarray(x, np.float32)
+    # a parked lane's output is never read, and its rows land in the
+    # scratch block, which every parked lane writes
+    assert_allclose(f32(got[0])[live], f32(want[0])[live], rtol=tol,
+                    atol=tol)
+    for g, w in zip(got[1:], want[1:]):
+        keep = np.arange(g.shape[1]) != SCRATCH_BLOCK
+        assert_allclose(f32(g)[:, keep], f32(w)[:, keep], rtol=0, atol=0)
+
+
+def test_live_block_span_counts_the_blocks_a_lane_reads():
+    from repro.models.attention import live_block_span
+
+    lens = np.array([0, 1, 16, 17, 2048])
+    first, end = live_block_span(lens, 16)
+    assert first.tolist() == [0] * 5 and end.tolist() == [0, 1, 1, 2, 128]
+    first, end = live_block_span(lens, 16, window=20)
+    # positions kv_len - 20 .. kv_len - 1
+    assert first.tolist() == [0, 0, 0, 0, 126] and end.tolist()[-1] == 128
+
+
+@pytest.mark.parametrize("arch, want", [
+    ("h2o_danube_1p8b", 64),  # a sliding window
+    ("smollm_360m", 0),
+    ("zamba2_2p7b", 0),  # the hybrid's shared block attends over all
+])
+def test_decode_window_is_the_window_the_decode_step_reads(arch, want):
+    from repro.configs import get_smoke_config
+    from repro.models.attention import decode_window
+
+    cfg = get_smoke_config(arch)
+    assert decode_window(cfg) == want
+    if cfg.family == "hybrid":  # even where a window is configured
+        assert decode_window(dataclasses.replace(cfg, sliding_window=8)) == 0

@@ -293,12 +293,11 @@ def _paged_step(kind):
         cfg = dataclasses.replace(cfg, w_bits=2)
     params = lm.init_params(cfg, jax.random.key(0))
     pool = KVPool.for_slots(cfg, slots=2, max_len=32, block_tokens=4)
-    s_max = pool.max_rows(32)
-    table = jnp.zeros((2, s_max), jnp.int32)
+    table = jnp.zeros((2, pool.blocks_for(32)), jnp.int32)
     lengths = jnp.zeros((2,), jnp.int32)
     token = jnp.zeros((2, 1), jnp.int32)
     chunk = (jnp.zeros((1, 8), jnp.int32), pool.k, pool.v, table[:1],
-             jnp.zeros((1, 8), jnp.int32), jnp.int32(0), jnp.int32(7))
+             jnp.int32(0), jnp.int32(7))
     lane = lm.init_ssm_lane_state(cfg, 2) if arch == "zamba2_2p7b" else None
     if kind == "decode":
         return (steps.make_paged_serve_step(cfg),
@@ -312,7 +311,7 @@ def _paged_step(kind):
     if kind == "verify":
         return (steps.make_verify_step(cfg),
                 (params, jnp.zeros((2, 3), jnp.int32), pool.k, pool.v, table,
-                 jnp.zeros((2, 3), jnp.int32), lengths))
+                 lengths))
     if kind == "hybrid_decode":
         return (steps.make_paged_serve_step(cfg),
                 (params, token, pool.k, pool.v, table, lengths, lane))
@@ -338,3 +337,113 @@ def test_paged_steps_name_their_kv_sub_layer(kind, program):
     paths = set(scope_table(text, lm.STEP_SCOPES).values())
     assert {"attention/kv_write", "attention/kv_gather", "ffn",
             "logits"} <= paths
+
+
+# --------------------------------------------------------------------------
+# the block-contiguous pool against the row-addressed formulation it
+# replaced: every lane's rows scattered into and gathered from a pool of
+# single rows through a per-position row table
+# --------------------------------------------------------------------------
+
+T_BLOCK, NB = 4, 8  # 4-token blocks, 8 table entries (32 positions)
+
+
+def _rows_attention(lp, cfg, x, pk, pv, layer, rows, table, positions,
+                    window):
+    """One attention sub-block over a row pool (L, R, n_kv, hd): K/V rows
+    scattered at ``rows`` (B, C), gathered through ``table`` (B, S)."""
+    b, c, _ = x.shape
+    q, k, v = lm._qkv(lp, cfg, x, positions)
+    pk = pk.at[layer, rows].set(k)
+    pv = pv.at[layer, rows].set(v)
+    o = attn.chunk_attention(q, pk[layer][table], pv[layer][table],
+                             positions, window=window)
+    return x + lm.dense(o.reshape(b, c, -1), lp["wo"]), pk, pv
+
+
+def _rows_step(params, cfg, tokens, pk, pv, table, rows, positions,
+               lane=None, last_idx=None):
+    """Logits of the row-addressed paged step, every family."""
+    x = lm.embed(tokens, params["embed"], lm._dt(cfg))
+    at = lambda tree, i: jax.tree.map(lambda a: a[i], tree)
+    if cfg.family == "hybrid":
+        every = cfg.hybrid_attn_every
+        for s in range(cfg.n_layers // every):
+            for i in range(s * every, (s + 1) * every):
+                bufs = tuple(lane[key][i]
+                             for key in ("conv_x", "conv_b", "conv_c"))
+                x, _, _ = lm._ssm_block(at(params["layers"], i), cfg, x,
+                                        state=lane["ssm"][i], conv_bufs=bufs)
+            x, pk, pv = _rows_attention(params["shared"], cfg, x, pk, pv, s,
+                                        rows, table, positions, 0)
+            x, _ = lm._ffn_block(params["shared"], cfg, x)
+    else:
+        for i in range(cfg.n_layers):
+            lp = at(params["layers"], i)
+            x, pk, pv = _rows_attention(lp, cfg, x, pk, pv, i, rows, table,
+                                        positions, cfg.sliding_window)
+            x, _ = lm._ffn_block(lp, cfg, x, dropless=cfg.family == "moe")
+    return lm._scoped_logits(params, cfg, x, last_idx), pk, pv
+
+
+@pytest.mark.parametrize("arch,kind", [
+    ("smollm_360m", "decode"), ("smollm_360m", "chunk"),
+    ("smollm_360m", "verify"), ("olmoe_1b_7b", "decode"),
+    ("olmoe_1b_7b", "chunk"), ("olmoe_1b_7b", "verify"),
+    ("internvl2_76b", "decode"), ("internvl2_76b", "chunk"),
+    ("internvl2_76b", "verify"), ("h2o_danube_1p8b", "decode"),
+    ("zamba2_2p7b", "decode"), ("zamba2_2p7b", "suffix"),
+])
+def test_block_pool_steps_match_the_row_formulation(arch, kind):
+    """Decode, chunk prefill and draft verification on the block pool give
+    the logits, and leave the K/V, of the row-addressed gather they
+    replaced, for dense (windowed too), moe, vlm and hybrid configs, with
+    lanes at different depths over scattered physical blocks."""
+    from repro.models.attention import pool_tile, tokens_to_tiles
+
+    cfg = get_smoke_config(arch)
+    params = lm.init_params(cfg, jax.random.key(3))
+    rng = np.random.default_rng(5)
+    n_lanes = 1 if kind in ("chunk", "suffix") else 3
+    n_blocks = 1 + n_lanes * NB
+    layers = cfg.n_kv_cache_layers
+    shape = (layers, n_blocks * T_BLOCK, cfg.n_kv, cfg.hd)
+    pk_rows = jnp.asarray(rng.normal(size=shape), jnp.float32)
+    pv_rows = jnp.asarray(rng.normal(size=shape), jnp.float32)
+    tile = pool_tile(cfg.n_kv, T_BLOCK, cfg.hd)
+    to_blocks = lambda p: tokens_to_tiles(p, T_BLOCK, tile)
+    block_table = (1 + rng.permutation(n_lanes * NB)).reshape(n_lanes, NB)
+    row_table = (block_table[:, :, None] * T_BLOCK
+                 + np.arange(T_BLOCK)).reshape(n_lanes, NB * T_BLOCK)
+    c = {"decode": 1, "verify": 3}.get(kind, 6)
+    starts = np.array([9, 3, 16][:n_lanes], np.int32)
+    tokens = rng.integers(0, cfg.vocab, size=(n_lanes, c)).astype(np.int32)
+    positions = starts[:, None] + np.arange(c)
+    rows = np.take_along_axis(row_table, positions, axis=1)
+    lane = (lm.init_ssm_lane_state(cfg, n_lanes)
+            if cfg.family == "hybrid" else None)
+    if lane is not None:
+        lane = jax.tree.map(
+            lambda a: jnp.asarray(rng.normal(size=a.shape) * 0.1, a.dtype),
+            lane)
+    last = jnp.int32(c - 1) if kind in ("chunk", "suffix") else None
+    want, want_k, _ = _rows_step(params, cfg, jnp.asarray(tokens), pk_rows,
+                                 pv_rows, row_table, rows,
+                                 jnp.asarray(positions), lane, last)
+    args = (params, cfg, jnp.asarray(tokens), to_blocks(pk_rows),
+            to_blocks(pv_rows), jnp.asarray(block_table, jnp.int32))
+    if kind == "decode" and lane is not None:
+        got = lm.decode_step_paged_hybrid(*args, jnp.asarray(starts), lane)
+    elif kind == "decode":
+        got = lm.decode_step_paged(*args, jnp.asarray(starts))
+    elif kind == "verify":
+        got = lm.verify_chunk_paged(*args, jnp.asarray(starts))
+    elif kind == "chunk":
+        got = lm.prefill_chunk_paged(*args, jnp.int32(starts[0]), last)
+    else:
+        got = lm.prefill_suffix_paged_hybrid(*args, jnp.int32(starts[0]),
+                                             last, lane)
+    assert_allclose(np.asarray(got[0]), np.asarray(want), rtol=2e-5,
+                    atol=2e-5)
+    assert_allclose(np.asarray(got[1]), np.asarray(to_blocks(want_k)),
+                    rtol=2e-5, atol=2e-5)

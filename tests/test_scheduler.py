@@ -53,9 +53,9 @@ def test_pool_alloc_free_invariants():
         pool.validate()
     pool.note_tokens(1, 12)
     pool.validate()
-    rows0, rows1 = pool.rows_of(0), pool.rows_of(1)
-    assert len(set(rows0.tolist()) & set(rows1.tolist())) == 0
-    assert len(rows0) == 16 and len(rows1) == 12
+    blocks0, blocks1 = pool.blocks_of(0), pool.blocks_of(1)
+    assert len(set(blocks0) & set(blocks1)) == 0
+    assert len(blocks0) * BLOCK == 16 and len(blocks1) * BLOCK == 12
     st = pool.stats()
     assert st.held_tokens == 28 and st.held_blocks == 7
     assert st.utilization == 28 / 28  # both requests exactly fill blocks
@@ -205,7 +205,7 @@ def test_paged_decode_matches_ring_path():
         np.asarray(pre_logits), np.asarray(ring_logits), rtol=1e-4, atol=1e-4
     )
 
-    s_max = pool.max_rows(MAX_LEN)
+    n_table = pool.blocks_for(MAX_LEN)
     lengths = np.full((b,), P, np.int32)
     token = np.argmax(np.asarray(pre_logits[:, 0, :]), -1).astype(np.int32)
     pk, pv = pool.k, pool.v
@@ -213,10 +213,10 @@ def test_paged_decode_matches_ring_path():
         ring_logits, cache = serve(params, jnp.asarray(token[:, None]), cache)
         for rid in range(b):
             pool.note_tokens(rid, int(lengths[rid]) + 1)
-        row_table = np.stack([pool.rows_of(r, pad_to=s_max) for r in range(b)])
+        table = np.stack([pool.table_of(r, n_table) for r in range(b)])
         paged_logits, pk, pv = lm.decode_step_paged(
             params, cfg, jnp.asarray(token[:, None]), pk, pv,
-            jnp.asarray(row_table), jnp.asarray(lengths),
+            jnp.asarray(table), jnp.asarray(lengths),
         )
         np.testing.assert_allclose(
             np.asarray(paged_logits), np.asarray(ring_logits),

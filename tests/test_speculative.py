@@ -266,6 +266,55 @@ def test_model_drafter_twin_token_identical():
     assert sched.stats.verify_steps <= N_REQ * math.ceil((GEN - 1) / 4)
 
 
+def test_drafter_lane_past_max_len_leaves_other_lanes_intact(monkeypatch):
+    """A drafter rolls every lane ``k`` steps, so a lane with one token
+    left (started at max_len - 2) writes rows at and past max_len. Through
+    the paged decode kernel (interpret mode) those rows go to the scratch
+    block: a short lane drafted beside it proposes the same tokens and
+    holds the same blocks as when it drafts beside a short neighbour."""
+    from repro.kernels import ops
+    from repro.kernels import paged_attention as pa
+    from repro.runtime import speculative
+    from repro.runtime.speculative import LaneDraft, ModelDrafter
+
+    cfg, params = _ctx()
+    monkeypatch.setattr(
+        ops, "paged_decode_runs_kernel", lambda hd, pool: True
+    )
+    monkeypatch.setattr(
+        ops, "paged_decode",
+        functools.partial(pa.paged_decode, interpret=True),
+    )
+    speculative._jitted_draft_decode.cache_clear()
+    max_len, k = 32, 4
+    rng = np.random.default_rng(3)
+    # past its first block, which a neighbour's stray row would overwrite
+    short = rng.integers(0, cfg.vocab, size=20).astype(np.int32)
+    full = rng.integers(0, cfg.vocab, size=max_len - 2).astype(np.int32)
+
+    def draft(neighbour):
+        # lanes 0 and 2 (the last) hold ``neighbour``, lane 1 ``short``
+        drafter = ModelDrafter(cfg, params, slots=3, max_len=max_len)
+        prompts = (neighbour, short, neighbour)
+        lanes = []
+        for slot, prompt in enumerate(prompts):
+            drafter.start_lane(slot, prompt)
+            lanes.append(LaneDraft(slot, slot, int(prompt[-1]), 0,
+                                   len(prompt), prompt))
+        props, _ = drafter.propose(lanes, k, lm.SamplingParams())
+        blocks = drafter._block_table[1]
+        return props[1], np.asarray(drafter.k[:, blocks]), np.asarray(
+            drafter.v[:, blocks]
+        )
+
+    try:
+        got, want = draft(full), draft(short)
+    finally:
+        speculative._jitted_draft_decode.cache_clear()
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(g, w)
+
+
 def test_twin_packing_round_trips_on_its_own_codebook():
     cfg, params = _ctx()
     dense = dequantize_ffn_params(params, 2)

@@ -68,19 +68,22 @@ def _spec(x, sharding):
 
 
 def _paged_args(cfg, sharding):
-    """Shapes of (params, token, pool_k, pool_v, row_table, lengths) for
-    LANES lanes of MAX_LEN rows each."""
+    """Shapes of (params, token, pool_k, pool_v, block_table, lengths)
+    for LANES lanes of MAX_LEN positions each."""
+    from repro.models.attention import pool_tile
+
     params = jax.tree.map(
         lambda x: _spec(x, sharding), lm.abstract_params(cfg)
     )
-    rows = (1 + LANES * (MAX_LEN // BLOCK_TOKENS)) * BLOCK_TOKENS
+    nb = MAX_LEN // BLOCK_TOKENS
     pool = jax.ShapeDtypeStruct(
-        (cfg.n_kv_cache_layers, rows, cfg.n_kv, cfg.hd),
+        (cfg.n_kv_cache_layers, 1 + LANES * nb)
+        + pool_tile(cfg.n_kv, BLOCK_TOKENS, cfg.hd),
         jnp.dtype(cfg.dtype),
         sharding=sharding,
     )
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=sharding)
-    return params, i32(LANES, 1), pool, pool, i32(LANES, MAX_LEN), i32(LANES)
+    return params, i32(LANES, 1), pool, pool, i32(LANES, nb), i32(LANES)
 
 
 @pytest.mark.parametrize("bits", [0, 1, 2])
@@ -106,13 +109,32 @@ def test_weight_stream_compiles(one_chip, tpu_kernels, bits, k, n):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_paged_decode_step_compiles(one_chip):
+def _kernel_scopes(text):
+    """Scope paths of the compiled program's Pallas kernel calls."""
+    from repro.perf.hlo_analysis import scope_table
+
+    table = scope_table(text, lm.STEP_SCOPES)
+    calls = [
+        line.split(" = ")[0].strip().lstrip("%")
+        for line in text.splitlines()
+        if "custom_call_target=\"tpu_custom_call\"" in line
+    ]
+    return {name: table.get(name) for name in calls}
+
+
+def test_paged_decode_step_compiles(one_chip, tpu_kernels):
+    """The decode step walks the block tables with the Pallas kernel, keeps
+    its program name, and updates the pool in place: no whole-pool copy."""
     cfg = get_config("smollm_360m")
+    args = _paged_args(cfg, one_chip)
     compiled = (
         jax.jit(make_paged_serve_step(cfg), donate_argnums=(2, 3))
-        .lower(*_paged_args(cfg, one_chip))
+        .lower(*args)
         .compile()
     )
+    text = compiled.as_text()
+    assert text.startswith("HloModule jit_step,")
+    assert "tpu_custom_call" in text
     mem = compiled.memory_analysis()
     # the step must fit one v5e's 16 GB of HBM
     used = (
@@ -122,6 +144,26 @@ def test_paged_decode_step_compiles(one_chip):
         - mem.alias_size_in_bytes
     )
     assert used < 16e9, used
+    # both pools come back in place, and no scratch could hold a copy
+    pool_bytes = args[2].size * args[2].dtype.itemsize
+    assert mem.alias_size_in_bytes >= 2 * pool_bytes
+    assert mem.temp_size_in_bytes < pool_bytes, mem.temp_size_in_bytes
+
+
+def test_paged_decode_kernel_is_in_the_kv_gather_scope(one_chip, tpu_kernels):
+    """The decode step's scope table (what ``step.decode_kv_ms`` reads) puts
+    the kernel call under ``attention/kv_gather``."""
+    cfg = get_config("smollm_360m")
+    compiled = (
+        jax.jit(make_paged_serve_step(cfg), donate_argnums=(2, 3))
+        .lower(*_paged_args(cfg, one_chip))
+        .compile()
+    )
+    scopes = _kernel_scopes(compiled.as_text())
+    assert scopes and all(
+        path is not None and "attention/kv_gather" in path
+        for path in scopes.values()
+    ), scopes
 
 
 def test_chunk_prefill_step_compiles(one_chip):
@@ -130,7 +172,7 @@ def test_chunk_prefill_step_compiles(one_chip):
     params, _, pool_k, pool_v, _, _ = _paged_args(cfg, one_chip)
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
     jax.jit(make_chunk_prefill_step(cfg), donate_argnums=(2, 3)).lower(
-        params, i32(1, 256), pool_k, pool_v, i32(1, MAX_LEN), i32(1, 256),
+        params, i32(1, 256), pool_k, pool_v, i32(1, MAX_LEN // BLOCK_TOKENS),
         i32(), i32(),
     ).compile()
 
@@ -151,4 +193,9 @@ def test_budgeted_paged_decode_step_compiles(one_chip, tpu_kernels):
         .lower(*_paged_args(cfg, one_chip))
         .compile()
     )
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert text.startswith("HloModule jit_step,")
+    # the streamed FFNs' weight_stream calls and the paged decode kernel
+    scopes = _kernel_scopes(text)
+    assert any("kv_gather" in (p or "") for p in scopes.values()), scopes
+    assert any("ffn" in (p or "") for p in scopes.values()), scopes
