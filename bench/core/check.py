@@ -18,7 +18,6 @@ ranks first, and reads the same gap.
 from __future__ import annotations
 
 import dataclasses
-import importlib
 import json
 import pathlib
 
@@ -58,10 +57,6 @@ def sample(served: list[Served], seed: int) -> list[Served]:
     return chosen
 
 
-def reference_module(name: str):
-    return importlib.import_module(f"bench.reference.{name}")
-
-
 def _inputs(s: Served, seq_len: int, rows_len: int):
     """Tokens (prompt + all served tokens but the last, right-padded) and
     the positions whose next-token logits produced each served token."""
@@ -75,15 +70,15 @@ def _inputs(s: Served, seq_len: int, rows_len: int):
 
 
 def gaps(
-    sizes, reference: str, weights, chosen: list[Served], seq_len: int,
+    family, sizes, weights, chosen: list[Served], seq_len: int,
     rows_len: int, control: bool = False,
 ) -> np.ndarray:
     """Per served token: reference best logit minus the reference logit of
     the token served (or, for the control, of the token the lower
-    precision ranks first at that position)."""
-    ref = reference_module(reference)
-    exact = ref.logits_at(sizes, "f32")
-    low = ref.logits_at(sizes, "fp8") if control else None
+    precision ranks first at that position). ``family`` is the
+    configuration's family module, whose ``logits_at`` is the reference."""
+    exact = family.logits_at(sizes, "f32")
+    low = family.logits_at(sizes, "fp8") if control else None
     out = []
     for s in chosen:
         tokens, rows = _inputs(s, seq_len, rows_len)

@@ -3,8 +3,14 @@
 The file keeps the published config's keys at its top level (Hugging Face
 names), the FFN weight width under ``quantization``, how the system
 serves it under ``serving``, and the sizes of the CPU rehearsal under
-``rehearsal``. ``reference`` names the plain reference implementation in
-``bench/reference/``.
+``rehearsal``. ``reference`` names the configuration's family module,
+``bench/reference/<reference>.py``, which reads the published keys and
+knows everything else of the family (its interface is in
+``bench/reference/__init__.py``); this module reads only what every
+family shares.
+
+``reduced`` maps each key cut for one chip to its published value, and
+``assumed`` each size the source does not give to the reason for it.
 """
 
 from __future__ import annotations
@@ -12,39 +18,19 @@ from __future__ import annotations
 import dataclasses
 import json
 import pathlib
+from types import ModuleType
+from typing import Any
 
-# the published keys the harness reads; every one must be in the file
-MODEL_KEYS = (
-    "num_hidden_layers",
-    "hidden_size",
-    "intermediate_size",
-    "num_attention_heads",
-    "num_key_value_heads",
-    "head_dim",
-    "vocab_size",
-    "max_position_embeddings",
-    "rope_theta",
-    "rms_norm_eps",
-    "tie_word_embeddings",
-    "torch_dtype",
+from bench.core import registry
+
+# the harness's own checkout: where a family module is found by default
+ROOT = pathlib.Path(__file__).resolve().parents[2]
+# the keys read here, whatever the family
+COMMON_KEYS = ("arch", "reference", "serving", "rehearsal", "quantization")
+# keys that describe the configuration and shape nothing
+DESCRIPTIVE_KEYS = (
+    "source", "model_type", "deployment", "derived", "reduced", "assumed",
 )
-
-
-@dataclasses.dataclass(frozen=True)
-class ModelSizes:
-    layers: int
-    hidden: int
-    intermediate: int
-    heads: int
-    kv_heads: int
-    head_dim: int
-    vocab: int
-    context: int
-    rope_theta: float
-    norm_eps: float
-    tied: bool
-    dtype: str
-    ffn_bits: int  # 0: FFN weights in ``dtype``; 1 or 2: packed carriers
 
 
 @dataclasses.dataclass(frozen=True)
@@ -66,30 +52,29 @@ class Serving:
 class ModelConfig:
     name: str
     arch: str  # the program's configuration id
-    reference: str  # module name under bench/reference/
-    sizes: ModelSizes
+    family: ModuleType  # bench/reference/<reference>.py
+    sizes: Any  # the family module's ``Sizes``
     serving: Serving
 
 
-def _sizes(top: dict, ffn_bits: int) -> ModelSizes:
-    missing = [k for k in MODEL_KEYS if k not in top]
-    if missing:
-        raise ValueError(f"configuration lacks {missing}")
-    return ModelSizes(
-        layers=int(top["num_hidden_layers"]),
-        hidden=int(top["hidden_size"]),
-        intermediate=int(top["intermediate_size"]),
-        heads=int(top["num_attention_heads"]),
-        kv_heads=int(top["num_key_value_heads"]),
-        head_dim=int(top["head_dim"]),
-        vocab=int(top["vocab_size"]),
-        context=int(top["max_position_embeddings"]),
-        rope_theta=float(top["rope_theta"]),
-        norm_eps=float(top["rms_norm_eps"]),
-        tied=bool(top["tie_word_embeddings"]),
-        dtype=str(top["torch_dtype"]),
-        ffn_bits=ffn_bits,
-    )
+def _check_keys(raw: dict, top: dict, family: ModuleType) -> None:
+    """Every top-level key is read by this module or by the family, and
+    every key ``reduced`` names is in the file, cut from its published
+    value."""
+    known = set(COMMON_KEYS) | set(DESCRIPTIVE_KEYS) | set(family.KEYS)
+    unread = sorted(set(top) - known)
+    if unread:
+        raise ValueError(
+            f"no part of the harness reads {unread}: the family module "
+            f"{raw['reference']!r} reads {sorted(family.KEYS)}"
+        )
+    for key, published in raw.get("reduced", {}).items():
+        if key not in raw:
+            raise ValueError(f"reduced key {key!r} is not in the file")
+        if raw[key] == published:
+            raise ValueError(
+                f"reduced key {key!r} keeps its published value {published!r}"
+            )
 
 
 def _serving(s: dict) -> Serving:
@@ -108,11 +93,12 @@ def _serving(s: dict) -> Serving:
 
 
 def load_config(
-    path: pathlib.Path, name: str, rehearsal: bool = False
+    path: pathlib.Path, name: str, rehearsal: bool = False,
+    root: pathlib.Path = ROOT,
 ) -> ModelConfig:
     """Read one configuration file; ``rehearsal`` applies the file's
     ``rehearsal`` group (CPU sizes) over its published keys and serving
-    settings."""
+    settings. The family module is ``root``'s."""
     raw = json.loads(pathlib.Path(path).read_text())
     top = dict(raw)
     serving = dict(raw["serving"])
@@ -120,12 +106,13 @@ def load_config(
         over = dict(raw["rehearsal"])
         serving.update(over.pop("serving", {}))
         top.update(over)
-    bits = int(raw.get("quantization", {}).get("ffn_weight_bits", 0))
+    family = registry.family(root, str(raw["reference"]))
+    _check_keys(raw, top, family)
     cfg = ModelConfig(
         name=name,
         arch=str(raw["arch"]),
-        reference=str(raw["reference"]),
-        sizes=_sizes(top, bits),
+        family=family,
+        sizes=family.sizes(top, dict(raw.get("quantization", {}))),
         serving=_serving(serving),
     )
     if cfg.serving.max_len > cfg.sizes.context:

@@ -7,8 +7,6 @@ the harness reaches into the program.
 
 from __future__ import annotations
 
-import dataclasses
-
 import numpy as np
 
 from bench.core.config import ModelConfig
@@ -16,29 +14,17 @@ from bench.core.traffic import Workload, bucket, prompt_shape_classes
 
 
 def program_config(cfg: ModelConfig):
-    """The program's ModelConfig at the benchmark configuration's sizes."""
+    """The program's ModelConfig at the benchmark configuration's sizes,
+    as the configuration's family maps them onto the program's own
+    configuration of ``arch``."""
     from repro.configs import get_config
 
-    m = cfg.sizes
-    return dataclasses.replace(
-        get_config(cfg.arch),
-        n_layers=m.layers,
-        d_model=m.hidden,
-        n_heads=m.heads,
-        n_kv=m.kv_heads,
-        head_dim=m.head_dim,
-        d_ff=m.intermediate,
-        vocab=m.vocab,
-        rope_theta=m.rope_theta,
-        norm_eps=m.norm_eps,
-        tie_embeddings=m.tied,
-        dtype=m.dtype,
-        w_bits=m.ffn_bits,
-    )
+    return cfg.family.program_config(cfg.sizes, get_config(cfg.arch))
 
 
 def serve_args(cfg: ModelConfig, seed: int):
     s = cfg.serving
+    w_bits = program_config(cfg).w_bits
     argv = [
         "--batch", str(s.lanes),
         "--max-len", str(s.max_len),
@@ -47,8 +33,8 @@ def serve_args(cfg: ModelConfig, seed: int):
         "--seed", str(seed),
         "--prefix-cache" if s.prefix_cache else "--no-prefix-cache",
     ]
-    if cfg.sizes.ffn_bits:
-        argv += ["--quant", str(cfg.sizes.ffn_bits)]
+    if w_bits:
+        argv += ["--quant", str(w_bits)]
     if s.vmem_budget_mib:
         argv += [
             "--vmem-budget", repr(s.vmem_budget_mib),

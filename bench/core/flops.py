@@ -1,53 +1,15 @@
-"""Operations and bytes the algorithm needs, computed from shapes.
+"""Operations and bytes of single calls, computed from shapes, and the
+roofline they set; whatever the family.
 
-These are the numerators of every utilization the benchmark reports.
-They count the work the model's equations require for the rows that are
-live, never what a program happens to compute: no padded rows, no
-gathered-but-masked cache rows, no recomputation.
+These are numerators of the utilizations the benchmark reports. A
+family's whole-model FLOP count lives in its family module
+(``bench/reference/<reference>.py``); like these, it counts the work the
+equations require for the rows that are live, never what a program
+happens to compute: no padded rows, no gathered-but-masked cache rows,
+no recomputation.
 """
 
 from __future__ import annotations
-
-from bench.core.config import ModelSizes
-
-
-def layer_matmul_flops(m: ModelSizes) -> int:
-    """FLOPs of one token through one layer's projections and FFN."""
-    q = 2 * m.hidden * m.heads * m.head_dim
-    kv = 2 * 2 * m.hidden * m.kv_heads * m.head_dim
-    o = 2 * m.heads * m.head_dim * m.hidden
-    ffn = 3 * 2 * m.hidden * m.intermediate
-    return q + kv + o + ffn
-
-
-def attention_flops(m: ModelSizes, keys: int) -> int:
-    """FLOPs of one query token attending over ``keys`` positions, all
-    layers: scores (QK) and the weighted sum (PV), 2 FLOPs per MAC."""
-    return m.layers * 4 * m.heads * m.head_dim * keys
-
-
-def unembed_flops(m: ModelSizes) -> int:
-    return 2 * m.hidden * m.vocab
-
-
-def span_flops(m: ModelSizes, start: int, n: int, logits_rows: int) -> int:
-    """FLOPs of ``n`` consecutive tokens at positions start..start+n-1,
-    each attending causally over every position up to its own, with
-    ``logits_rows`` of them unembedded."""
-    if n <= 0:
-        return 0
-    # sum over p of (p + 1) keys for p in [start, start + n)
-    keys = n * start + n * (n + 1) // 2
-    return (
-        n * m.layers * layer_matmul_flops(m)
-        + attention_flops(m, keys)
-        + logits_rows * unembed_flops(m)
-    )
-
-
-def decode_token_flops(m: ModelSizes, position: int) -> int:
-    """One decoded token at ``position``: its row is unembedded."""
-    return span_flops(m, position, 1, 1)
 
 
 def packed_matmul_cost(

@@ -9,6 +9,8 @@ by adding files and entries, not by editing a file that exists.
 - limits of the correctness check: ``bench/limits/<workload>.json``
 - end-to-end metric: ``bench/end_to_end/<name>.py``
 - per-layer metric: ``bench/metrics/<name>.py``
+- model family: ``bench/reference/<reference>.py``, named by the
+  configuration file's ``reference`` key
 
 A metric file defines ``read(run) -> float | None``; None means it found
 nothing to read in this run, and the metric is left out of the line.
@@ -17,9 +19,12 @@ nothing to read in this run, and the metric is left out of the line.
 from __future__ import annotations
 
 import dataclasses
+import functools
+import hashlib
 import importlib.util
 import json
 import pathlib
+import sys
 from types import ModuleType
 
 
@@ -76,5 +81,23 @@ def reader(root: pathlib.Path, kind: str, name: str) -> ModuleType:
     if spec is None or not path.is_file():
         raise FileNotFoundError(f"no reader for metric {name!r} at {path}")
     mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+@functools.lru_cache(maxsize=None)
+def family(root: pathlib.Path, name: str) -> ModuleType:
+    """The family module ``bench/reference/<name>.py`` of ``root``, loaded
+    from its file once per process (its jitted functions and weights
+    cache with it)."""
+    path = (pathlib.Path(root) / "bench" / "reference" / f"{name}.py").resolve()
+    if not path.is_file():
+        raise FileNotFoundError(f"no family module {name!r} at {path}")
+    key = hashlib.sha256(str(path).encode()).hexdigest()[:12]
+    mod_name = f"bench_family_{name}_{key}"
+    spec = importlib.util.spec_from_file_location(mod_name, path)
+    mod = importlib.util.module_from_spec(spec)
+    # dataclasses resolve their module by name while the class is made
+    sys.modules[mod_name] = mod
     spec.loader.exec_module(mod)
     return mod

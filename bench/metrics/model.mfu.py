@@ -6,15 +6,16 @@ over the host time of the window's rounds times the peak.
 
 Prefilled tokens come from the program's prefill spans (start and
 length of each chunk); decoded tokens from the logits hook, each at its
-own position. Only a prompt's last chunk needs its logits row."""
-
-from bench.core.flops import decode_token_flops, span_flops
+own position. Only a prompt's last chunk needs its logits row. The FLOP
+count is the configuration's family module's."""
 
 
 def read(run):
     if run.peaks is None or run.spans is None:
         return None
     m = run.cfg.sizes
+    span_flops = run.cfg.family.span_flops
+    decode_token_flops = run.cfg.family.decode_token_flops
     flops = 0
     for s in run.spans_of("prefill"):
         if run.in_window(s["t1"]):

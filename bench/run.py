@@ -106,7 +106,6 @@ def run_cell(
     from bench.core.stats import TooFewSamples
     from bench.core.trace import summarize
     from bench.core.traffic import generate, load_mix
-    from bench.core.weights import make_weights
     from bench.core.window import CompileCounter, counters, drive
 
     # set-up phases, to tell which part of set-up moves between runs
@@ -115,7 +114,8 @@ def run_cell(
     dev = device_info(jax, rehearsal, cell.chips)
     phases.append(("devices", time.monotonic()))
     peaks = None if rehearsal else peaks_for(dev["kind"])
-    cfg = load_config(cell.config_file, cell.config_name, rehearsal)
+    cfg = load_config(cell.config_file, cell.config_name, rehearsal, root)
+    family = cfg.family
     mix = load_mix(cell.traffic_file, rehearsal)
     if rate_per_s is not None:
         mix["rate_per_s"] = rate_per_s
@@ -124,7 +124,7 @@ def run_cell(
     compiles = CompileCounter()
 
     padded_vocab = program_config(cfg).padded_vocab
-    weights = make_weights(cfg.sizes, seed, padded_vocab)
+    weights = family.make_weights(cfg.sizes, seed, padded_vocab)
     jax.block_until_ready(weights)
     phases.append(("weights", time.monotonic()))
     sched = build_engine(cfg, weights, seed)
@@ -199,14 +199,14 @@ def run_cell(
     )
 
     chosen = check.sample(served, seed)
-    ref_weights = make_weights(cfg.sizes, seed, padded_vocab)
+    ref_weights = family.make_weights(cfg.sizes, seed, padded_vocab)
     gaps = check.gaps(
-        cfg.sizes, cfg.reference, ref_weights, chosen,
+        family, cfg.sizes, ref_weights, chosen,
         cfg.serving.max_len, work.max_new,
     )
     if control:
         low = check.gaps(
-            cfg.sizes, cfg.reference, ref_weights, chosen,
+            family, cfg.sizes, ref_weights, chosen,
             cfg.serving.max_len, work.max_new, control=True,
         )
     del ref_weights

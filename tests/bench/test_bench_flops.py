@@ -6,9 +6,9 @@ import pathlib
 import pytest
 
 from bench.core.config import load_config
-from bench.core.flops import (
-    decode_token_flops, layer_matmul_flops, packed_matmul_cost,
-    roofline_seconds, span_flops,
+from bench.core.flops import packed_matmul_cost, roofline_seconds
+from bench.reference.llama import (
+    decode_token_flops, layer_matmul_flops, span_flops,
 )
 
 ROOT = pathlib.Path(__file__).resolve().parents[2]

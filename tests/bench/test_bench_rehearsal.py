@@ -14,7 +14,7 @@ from bench.core.engine import (
     build_engine, program_config, warm_up, warmup_requests,
 )
 from bench.core.traffic import generate, load_mix
-from bench.core.weights import make_weights
+from bench.reference.llama import make_weights
 from bench.run import run_cell
 
 ROOT = pathlib.Path(__file__).resolve().parents[2]
